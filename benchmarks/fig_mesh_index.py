@@ -85,7 +85,7 @@ def run() -> list:
     for D in [d for d in (1, 2, 4, 8) if d <= avail]:
         mesh = lmesh.make_index_mesh(D)
         mx = mi.build_mesh_index(jnp.asarray(keys), jnp.asarray(vals),
-                                 n_devices=D, n_shards=N_SHARDS,
+                                 mesh=mesh, n_devices=D, n_shards=N_SHARDS,
                                  levels=LEVELS)
         state_dev = _tree_bytes(mx.local) // D
         model_dev = ml.dma_model_bytes_mesh(mx, BATCH)

@@ -24,6 +24,8 @@ import time
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (fig3_sequential, fig4_batch_sweep,
                             fig6_size_sweep, fig8_access_counters,
                             fig_fat_node, fig_mesh_index, fig_shard_skew,

@@ -1,12 +1,8 @@
-"""Pallas kernel budget / aliasing checker + the canonical VMEM estimator.
+"""Pallas kernel budget / aliasing checker.
 
-The VMEM-footprint formula used to live in three places — the kernel
-dispatcher (``kernels/ops.py`` ``vmem_footprint``/``shard_vmem_footprint``),
-the store's monolithic-tile check (``data/store.py``) and every budget
-assertion in tests — and nothing tied them together, so the builder's idea
-of "fits" could drift from the checker's.  ``tile_bytes`` below is now the
-ONE estimator: ``kernels.ops`` delegates to it and this module's checks
-assert against the same constant the builders read.
+The VMEM estimator ``tile_bytes`` and the budget constants live with the
+kernels they size (``kernels/foresight_traverse.py``); the builders and
+this checker read the same ones, so their ideas of "fits" cannot drift.
 
 The checker itself never executes a kernel.  ``capture_pallas_calls``
 monkeypatches ``pallas_call`` so that invoking a wrapper records each
@@ -47,34 +43,13 @@ from typing import Dict, List, Optional, Sequence, Tuple
 from repro.analysis.findings import Finding
 
 # ---------------------------------------------------------------------------
-# Canonical budget constants + footprint estimator (the dedup target)
+# Budget constants + footprint estimator (owned by the kernels)
 # ---------------------------------------------------------------------------
 
-TOTAL_VMEM_BYTES = 16 * 1024 * 1024   # one TPU core's VMEM
-VMEM_BUDGET_BYTES = 12 * 1024 * 1024  # index-tile budget (headroom for I/O
-                                      # blocks and compiler temporaries)
+from repro.kernels.foresight_traverse import (VMEM_BUDGET_BYTES,  # noqa: F401
+                                              VMEM_LIMIT_BYTES, tile_bytes)
 
-
-def tile_bytes(levels: int, capacity: int, foresight: bool,
-               node_width: int = 1) -> int:
-    """Bytes one skiplist index tile occupies in VMEM.
-
-    foresight: ``levels * capacity`` fused (ptr, key) int32 pairs;
-    base: ``levels * capacity`` int32 pointers + ``capacity`` int32 keys.
-    Fat layout (``node_width`` > 1) adds the ``fat_keys`` run tile
-    (``capacity * node_width`` int32) — ``capacity`` counts NODE slots
-    there, so for a fixed element count the skip tables shrink by the
-    fill factor while the run tile holds the elements themselves
-    (``fat_vals`` never ships to a kernel; values resolve outside).
-    This is THE estimator — ``kernels.ops.shard_vmem_footprint`` and the
-    store's monolithic-tile check both delegate here, so the builder and
-    the checker cannot disagree about what fits.
-    """
-    base = (levels * capacity * 2 * 4 if foresight
-            else levels * capacity * 4 + capacity * 4)
-    if node_width > 1:
-        base += capacity * node_width * 4
-    return base
+TOTAL_VMEM_BYTES = VMEM_LIMIT_BYTES   # the scoped VMEM each kernel requests
 
 
 def max_capacity_under_budget(levels: int, foresight: bool,
@@ -119,7 +94,9 @@ def _kernel_name(kernel) -> str:
 
 
 def _spec_fields(spec):
-    shape = tuple(getattr(spec, "block_shape", ()) or ())
+    # a squeezed dim (``None``) is a block of extent 1 along that axis
+    shape = tuple(d if isinstance(d, int) else 1
+                  for d in (getattr(spec, "block_shape", ()) or ()))
     return shape, getattr(spec, "index_map", None)
 
 
@@ -409,7 +386,7 @@ def probe_repo_kernels() -> Tuple[List[Finding], List[str]]:
         # the ClusterPlan or explicit arrays) for index_map evaluation
         caps: List[LaunchCapture] = []
         with capture_pallas_calls(caps, capture_only=True):
-            fn(*args, **kw)
+            fn(*args, interpret=True, **kw)
         if plan is not None:
             prefetch = (np.asarray(plan.block_sids), np.asarray(plan.ndist))
             ndist = np.asarray(plan.ndist)
@@ -506,15 +483,14 @@ def probe_repo_kernels() -> Tuple[List[Finding], List[str]]:
             run(ft.base_traverse_sharded, shl.shards.nxt, shl.shards.keys,
                 sidf, qf, shl.shards.fat_keys)
 
-    # Production-maximal fat sweep at node_width = QBLK.  Sized to fit the
-    # TOTAL budget even double-buffered (budget = TOTAL/2): capacity counts
+    # Production-maximal fat sweep at node_width = QBLK.  Sized to the tile
+    # budget, so it fits the TOTAL even double-buffered: capacity counts
     # node slots, so a fitting fat tile still serves node_width-fold more
     # elements than the scalar maximal tile above — the fat layout's whole
     # point — and the gate stays green with no new baselined findings.
     nw = QBLK
     for foresight in (True, False):
-        cap_f = max_capacity_under_budget(L, foresight,
-                                          TOTAL_VMEM_BYTES // 2,
+        cap_f = max_capacity_under_budget(L, foresight, VMEM_BUDGET_BYTES,
                                           node_width=nw)
         fatk1 = jnp.zeros((cap_f, nw), jnp.int32)
         fatkS = jnp.zeros((2, cap_f, nw), jnp.int32)

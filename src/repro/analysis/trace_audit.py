@@ -30,7 +30,7 @@ Entry points audited (the ISSUE list):
 * ``core.versioned`` publish/read (``VersionedIndex.search`` /
   ``update`` per read view)
 
-Everything runs on CPU with ``interpret=True`` plumbed through, so the
+Everything runs on CPU, where the kernels run interpreted, so the
 audit is hardware-independent and CI-safe.
 """
 from __future__ import annotations
@@ -154,8 +154,7 @@ def _build_search_sharded(foresight: bool, cluster: bool,
                             node_width=node_width)
 
     def fn(q):
-        return kops.search_kernel_sharded(
-            shl, q, interpret=True, cluster=cluster)
+        return kops.search_kernel_sharded(shl, q, cluster=cluster)
 
     buckets = {
         "qblk": [(jnp.full((128,), 30, jnp.int32),),
@@ -293,7 +292,8 @@ def _mesh_fixture():
     n = 64
     keys = jnp.arange(1, n + 1, dtype=jnp.int32) * 5
     vals = jnp.arange(n, dtype=jnp.int32)
-    mx = mi.build_mesh_index(keys, vals, n_devices=1, n_shards=4, levels=4)
+    mx = mi.build_mesh_index(keys, vals, mesh=mesh, n_devices=1, n_shards=4,
+                             levels=4)
     return mesh, mx
 
 
@@ -322,7 +322,7 @@ def _build_mesh(which: str):
 
         def fn(local, db, q):
             return ml.search_kernel_mesh(mi.MeshShardedIndex(local, db), q,
-                                         mesh=mesh, interpret=True)
+                                         mesh=mesh)
 
         return fn, {
             "q128": [(mx.local, mx.device_boundaries,
@@ -333,7 +333,7 @@ def _build_mesh(which: str):
 
     # apply path, with device-local rebalancing on (the serving config)
     from repro.core import skiplist as sl
-    emp = mi.empty_mesh_index(n_devices=1, n_shards=4, capacity=64,
+    emp = mi.empty_mesh_index(mesh=mesh, n_devices=1, n_shards=4, capacity=64,
                               levels=4, key_span=1 << 20)
 
     def fn(local, db, op_types, keys, vals):

@@ -48,7 +48,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax.sharding import NamedSharding
 
 from repro.core.rebalance_traced import DeviceLoadStats, cross_device_load
 from repro.core.sharded import (HIGH_WATER, LOW_WATER, ShardedSkipList,
@@ -107,9 +107,9 @@ def route_devices(mx: MeshShardedIndex, queries: jax.Array) -> jax.Array:
     return route(mx.device_boundaries, queries)
 
 
-def build_mesh_index(keys: jax.Array, vals: jax.Array, *, n_devices: int,
-                     n_shards: int, capacity: int = 0, levels: int = 16,
-                     foresight: bool = True, seed: int = 0,
+def build_mesh_index(keys: jax.Array, vals: jax.Array, *, mesh,
+                     n_devices: int, n_shards: int, capacity: int = 0,
+                     levels: int = 16, foresight: bool = True, seed: int = 0,
                      node_width: int = 1) -> MeshShardedIndex:
     """Partition sorted unique int32 ``keys`` across ``n_devices`` slices.
 
@@ -120,7 +120,8 @@ def build_mesh_index(keys: jax.Array, vals: jax.Array, *, n_devices: int,
     ``partition_boundaries`` stride rule as the per-shard boundaries.
     Eager build (like ``build_sharded`` it is called once per index
     lifetime); the result feeds the jitted ``search_mesh`` /
-    ``apply_ops_mesh`` data path.
+    ``apply_ops_mesh`` data path.  Each slice is built on, and stays on,
+    its own device of the ``mesh`` it will run on (``_assemble``).
     """
     D = int(n_devices)
     if D < 1:
@@ -138,19 +139,17 @@ def build_mesh_index(keys: jax.Array, vals: jax.Array, *, n_devices: int,
         vals = jnp.concatenate([vals, jnp.full((pad,), NULL_VAL, jnp.int32)])
         valid = jnp.concatenate([valid, jnp.zeros((pad,), jnp.bool_)])
 
-    states = []
-    for d in range(D):
-        states.append(build_sharded(
+    def slice_d(d):
+        return build_sharded(
             keys[d * m:(d + 1) * m], vals[d * m:(d + 1) * m],
             n_shards=n_shards, capacity=capacity, levels=levels,
             foresight=foresight, seed=seed + d * n_shards,
-            valid=valid[d * m:(d + 1) * m], node_width=node_width))
-    local = jax.tree.map(lambda *xs: jnp.stack(xs), *states)
-    return MeshShardedIndex(local=local,
-                            device_boundaries=partition_boundaries(keys, m))
+            valid=valid[d * m:(d + 1) * m], node_width=node_width)
+
+    return _assemble(slice_d, D, partition_boundaries(keys, m), mesh)
 
 
-def empty_mesh_index(*, n_devices: int, n_shards: int, capacity: int,
+def empty_mesh_index(*, mesh, n_devices: int, n_shards: int, capacity: int,
                      levels: int = 16, foresight: bool = True, seed: int = 0,
                      key_span: int = int(KEY_MAX),
                      node_width: int = 1) -> MeshShardedIndex:
@@ -162,18 +161,47 @@ def empty_mesh_index(*, n_devices: int, n_shards: int, capacity: int,
     dense in ``[0, key_span)`` (e.g. the page-key space of the paged KV
     cache) get balanced devices by construction.  Each device starts as
     an ``empty_sharded``-style state built at ``n_shards`` (the per-
-    device ceiling when applied with ``rebalance=True``).
+    device ceiling when applied with ``rebalance=True``), placed like
+    ``build_mesh_index``'s slices when ``mesh`` is given.
     """
     D = int(n_devices)
     z = jnp.zeros((0,), jnp.int32)
-    states = [build_sharded(z, z, n_shards=n_shards, capacity=capacity,
-                            levels=levels, foresight=foresight,
-                            seed=seed + d * n_shards, node_width=node_width)
-              for d in range(D)]
-    local = jax.tree.map(lambda *xs: jnp.stack(xs), *states)
     step = max(1, key_span // D)
     db = (jnp.arange(D, dtype=jnp.int32) * step).at[0].set(KEY_MIN)
-    return MeshShardedIndex(local=local, device_boundaries=db)
+    return _assemble(
+        lambda d: build_sharded(z, z, n_shards=n_shards, capacity=capacity,
+                                levels=levels, foresight=foresight,
+                                seed=seed + d * n_shards,
+                                node_width=node_width),
+        D, db, mesh)
+
+
+def _assemble(slice_d, D: int, device_boundaries: jax.Array,
+              mesh) -> MeshShardedIndex:
+    """Stack the ``D`` device slices ``slice_d(d)`` into one index.
+
+    Each slice is built on, and stays on, its own device of the ``mesh``'s
+    ``index`` axis: the stacked leaves carry a ``NamedSharding`` over that
+    axis, so the jitted data path finds every shard where it already
+    lives.
+    """
+    if D != mesh.shape[INDEX_AXIS]:
+        raise ValueError(f"n_devices={D} but the mesh's {INDEX_AXIS!r} "
+                         f"axis has {mesh.shape[INDEX_AXIS]} devices")
+    devices = list(mesh.devices.reshape(-1))
+    states = []
+    for dev in devices:
+        with jax.default_device(dev):
+            st = slice_d(len(states))
+        states.append(jax.device_put(st, dev))   # committed to ``dev``
+    sharding = NamedSharding(mesh, index_state_spec())
+    local = jax.tree.map(
+        lambda *xs: jax.make_array_from_single_device_arrays(
+            (D,) + xs[0].shape, sharding, [x[None] for x in xs]),
+        *states)
+    db = jax.device_put(device_boundaries,
+                        NamedSharding(mesh, index_replicated_spec()))
+    return MeshShardedIndex(local, db)
 
 
 # ---------------------------------------------------------------------------
@@ -270,12 +298,12 @@ def _search_fn(mesh):
         vals_b = _exchange_back(vals, perm, starts, did_s, D)
         return found_b.astype(jnp.bool_), vals_b
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(index_state_spec(), index_replicated_spec(),
                   index_batch_spec()),
         out_specs=(index_batch_spec(), index_batch_spec()),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(fn)
 
 
@@ -321,14 +349,14 @@ def _apply_fn(mesh, rebalance, high_water, low_water, max_shards,
         return (jax.tree.map(lambda a: a[None], new_local), res_b,
                 live[None], routed[None])
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(index_state_spec(), index_replicated_spec(),
                   index_batch_spec(), index_batch_spec(), index_batch_spec(),
                   index_replicated_spec()),
         out_specs=(index_state_spec(), index_batch_spec(),
                    index_batch_spec(), index_batch_spec()),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(fn)
 
 

@@ -230,47 +230,44 @@ def _build_scalar(keys: jax.Array, vals: jax.Array, *, capacity: int,
     keys = jnp.where(valid, keys.astype(jnp.int32), KEY_MAX)
     vals = jnp.where(valid, vals.astype(jnp.int32), NULL_VAL)
 
-    ids = jnp.arange(2, n + 2, dtype=jnp.int32)          # node id per position
-    new_keys = st.keys.at[ids].set(keys.astype(jnp.int32))
-    new_vals = st.vals.at[ids].set(vals.astype(jnp.int32))
-    new_height = st.height.at[ids].set(heights)
+    # Elements take the contiguous node ids 2 .. n+1, so every write below
+    # is a static slice, never a scatter.
+    new_keys = st.keys.at[2:n + 2].set(keys)
+    new_vals = st.vals.at[2:n + 2].set(vals)
+    new_height = st.height.at[2:n + 2].set(heights)
 
-    # succ_pos[l, i] = first position j >= i with heights[j] > l (else n).
-    lvl = jnp.arange(levels, dtype=jnp.int32)[:, None]    # [L, 1]
-    reach = heights[None, :] > lvl                        # [L, n]
-    pos = jnp.arange(n, dtype=jnp.int32)[None, :]
-    cand = jnp.where(reach, pos, n)
-    suffix_min = lax.cummin(cand[:, ::-1], axis=1)[:, ::-1]   # [L, n]
+    pos = jnp.arange(n, dtype=jnp.int32)
 
-    # Successor *of node at position i* on level l = next reaching pos > i.
-    succ_pos = jnp.concatenate(
-        [suffix_min[:, 1:], jnp.full((levels, 1), n, jnp.int32)], axis=1)
-    succ_id = jnp.where(succ_pos >= n, TAIL, succ_pos + 2).astype(jnp.int32)
-    succ_key = jnp.where(succ_pos >= n, KEY_MAX,
-                         keys[jnp.clip(succ_pos, 0, n - 1)]).astype(jnp.int32)
+    def link_level(lvl, table):
+        """Level ``lvl``'s links; one level at a time keeps the build's
+        temporaries at O(n) instead of O(levels * n)."""
+        reach = heights > lvl                             # [n]
+        # suffix_min[i] = first position j >= i whose tower reaches lvl
+        suffix_min = lax.cummin(jnp.where(reach, pos, n)[::-1])[::-1]
+        first_pos = suffix_min[0] if n > 0 else jnp.int32(n)
+        # successor of the node at position i = next reaching position > i
+        succ_pos = jnp.concatenate([suffix_min[1:],
+                                    jnp.full((1,), n, jnp.int32)])
+        succ_id = jnp.where(succ_pos >= n, TAIL, succ_pos + 2)
+        succ_key = jnp.where(succ_pos >= n, KEY_MAX,
+                             keys[jnp.clip(succ_pos, 0, n - 1)])
+        head_id = jnp.where(first_pos >= n, TAIL, first_pos + 2)
+        head_key = jnp.where(first_pos >= n, KEY_MAX,
+                             keys[jnp.clip(first_pos, 0, n - 1)])
+        succ_id = jnp.where(reach, succ_id, 0)            # only real levels
+        if foresight:
+            succ_key = jnp.where(reach, succ_key, 0)
+            rows = jnp.stack([succ_id, succ_key], axis=-1)[None]
+            table = lax.dynamic_update_slice(table, rows, (lvl, 2, 0))
+            head = jnp.stack([head_id, head_key])[None, None]
+            return lax.dynamic_update_slice(table, head, (lvl, HEAD, 0))
+        table = lax.dynamic_update_slice(table, succ_id[None], (lvl, 2))
+        return lax.dynamic_update_slice(table, head_id[None, None],
+                                        (lvl, HEAD))
 
-    # Head successor on level l = first reaching position (suffix_min[:, 0]).
-    first_pos = suffix_min[:, 0] if n > 0 else jnp.full((levels,), n, jnp.int32)
-    head_id = jnp.where(first_pos >= n, TAIL, first_pos + 2).astype(jnp.int32)
-    head_key = jnp.where(first_pos >= n, KEY_MAX,
-                         keys[jnp.clip(first_pos, 0, n - 1)]).astype(jnp.int32)
-
-    mask = reach                                          # only link real levels
-    if foresight:
-        fused = st.fused
-        cur = fused[:, ids, :]
-        upd = jnp.stack([jnp.where(mask, succ_id, cur[..., 0]),
-                         jnp.where(mask, succ_key, cur[..., 1])], axis=-1)
-        fused = fused.at[:, ids, :].set(upd)
-        fused = fused.at[:, HEAD, 0].set(head_id)
-        fused = fused.at[:, HEAD, 1].set(head_key)
-        nxt = None
-    else:
-        nxt = st.nxt
-        cur = nxt[:, ids]
-        nxt = nxt.at[:, ids].set(jnp.where(mask, succ_id, cur))
-        nxt = nxt.at[:, HEAD].set(head_id)
-        fused = None
+    table = lax.fori_loop(0, levels, link_level,
+                          st.fused if foresight else st.nxt)
+    nxt, fused = (None, table) if foresight else (table, None)
 
     # Padded (invalid) slots are bit-identical to never-used ones (KEY_MAX
     # key, zero height, unlinked), so the bump allocator stops at the live
@@ -342,6 +339,18 @@ def _gather_fused(fused: jax.Array, lvl: jax.Array, x: jax.Array):
     cap = fused.shape[1]
     flat = fused.reshape((-1, 2))
     rec = jnp.take(flat, lvl * cap + x, axis=0)           # [B, 2]
+    return rec[..., 0], rec[..., 1]
+
+
+def _read_fused(fused: jax.Array, lvl: jax.Array, x: jax.Array):
+    """``_gather_fused`` for read-only programs: indexes the table in place.
+
+    The write path keeps ``_gather_fused``: on TPU, a loop that reads the
+    table this way followed by an in-place write to it makes the compiler
+    copy the whole table into a layout 64 times its size, where the flat
+    view costs one copy of its own size.
+    """
+    rec = fused[lvl, x]                                   # [B, 2]
     return rec[..., 0], rec[..., 1]
 
 
@@ -517,7 +526,7 @@ def search_fast(state: SkipListState, queries: jax.Array
         active = lvl >= 0
         safe_lvl = jnp.maximum(lvl, 0)
         if state.foresight:
-            ptr, fk = _gather_fused(state.fused, safe_lvl, x)
+            ptr, fk = _read_fused(state.fused, safe_lvl, x)
         else:
             ptr, fk = _gather_base(state.nxt, state.keys, safe_lvl, x)
         go = active & (fk < q)
@@ -525,7 +534,7 @@ def search_fast(state: SkipListState, queries: jax.Array
 
     x, lvl = lax.while_loop(cond, body, (x, lvl))
     if state.foresight:
-        cand, ck = _gather_fused(state.fused, jnp.zeros((B,), jnp.int32), x)
+        cand, ck = _read_fused(state.fused, jnp.zeros((B,), jnp.int32), x)
     else:
         cand, ck = _gather_base(state.nxt, state.keys,
                                 jnp.zeros((B,), jnp.int32), x)

@@ -75,9 +75,10 @@ class VersionedIndex:
             if use_kernel:
                 from repro.kernels.validated_traverse import \
                     validated_traverse
-                from repro.kernels.ops import _pad
+                from repro.kernels.ops import _pad, interpret_mode
                 q, B = _pad(queries.astype(jnp.int32))
-                node, ck = validated_traverse(view.fused, view.auth_keys, q)
+                node, ck = validated_traverse(view.fused, view.auth_keys, q,
+                                              interpret=interpret_mode())
                 node, ck = node[:B], ck[:B]
                 found = ck == queries.astype(jnp.int32)
                 vals = jnp.where(found, jnp.take(view.vals, node), -1)

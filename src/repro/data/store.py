@@ -66,6 +66,9 @@ class StoreConfig:
     seed: int = 0
 
 
+_apply_donated = jax.jit(sl.apply_ops, donate_argnums=(0,))
+
+
 class IndexedSampleStore:
     """rows: [N, seq_len+1] tokens; index: key -> row (Foresight skiplist)."""
 
@@ -88,11 +91,9 @@ class IndexedSampleStore:
             # The VMEM budget only binds the kernel path; the pure-JAX path
             # has no tile constraint, so auto keeps it monolithic (sharding
             # there would just cost S-times apply_ops work for nothing).
-            from repro.analysis.kernel_budget import (VMEM_BUDGET_BYTES,
-                                                      tile_bytes)
-            mono_tile = tile_bytes(cfg.index_levels, cap, cfg.foresight)
+            mono_tile = kops.tile_bytes(cfg.index_levels, cap, cfg.foresight)
             needs_shards = cfg.use_kernel and \
-                mono_tile > VMEM_BUDGET_BYTES
+                mono_tile > kops.VMEM_BUDGET_BYTES
             self.n_shards = kops.auto_shards(
                 cfg.n_samples, cfg.index_levels,
                 cfg.foresight) if needs_shards else 1
@@ -156,7 +157,9 @@ class IndexedSampleStore:
                 self.index = shd.repack(self.index, seed=self.cfg.seed)
                 self._updates_since_repack = 0
         else:
-            self.index, results = sl.apply_ops(self.index, ops, keys, vals)
+            # donated: the old table's buffers become the new one's, so a
+            # table near the device's size is never held twice
+            self.index, results = _apply_donated(self.index, ops, keys, vals)
         return results
 
     def ingest(self, keys: jax.Array, row_ids: jax.Array) -> jax.Array:

@@ -88,10 +88,11 @@ step ceiling — every wrapper (and the degeneration split in ``ops.py``)
 re-derives its launch from the shapes of the state it is handed on THAT
 call, which is the rebalance-safety contract above.
 
-Kernels are validated in ``interpret=True`` mode on CPU (bit-exact against
-``ref.py``); block shapes keep the minor dimension at 128 lanes and the
-fused pair in the minor-most axis so a real-TPU lowering fetches both halves
-in one transaction.
+Every wrapper takes a required ``interpret`` flag; the public entry
+points (``ops.py``, ``mesh_launch.py``) pass ``interpret_mode()``, which is
+true on the CPU backend only.  Kernels are validated interpreted on CPU
+(bit-exact against ``ref.py``) and compiled by Mosaic on a TPU; tables
+reach them as lane-dense ``[rows, 128]`` planes (see ``_fused_plane``).
 """
 from __future__ import annotations
 
@@ -109,6 +110,36 @@ from jax.experimental.pallas import tpu as pltpu
 _KEY_MAX = 2**31 - 1
 
 QBLK = 128     # query lanes per grid step == VPU lane width
+
+# Scoped VMEM each kernel asks Mosaic for.  The default scoped limit
+# (16 MiB on v5e) cannot hold a budget-sized index tile twice, and Pallas
+# double-buffers a tile whose block index changes across the grid (the
+# sharded and clustered launches).  v5e has 128 MiB of VMEM per core.
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+VMEM_BUDGET_BYTES = 12 * 1024 * 1024  # index-tile budget (headroom for I/O
+                                      # blocks and compiler temporaries)
+
+
+def tile_bytes(levels: int, capacity: int, foresight: bool,
+               node_width: int = 1) -> int:
+    """Bytes one skiplist index tile occupies in VMEM.
+
+    foresight: ``levels * capacity`` fused (ptr, key) int32 pairs;
+    base: ``levels * capacity`` int32 pointers + ``capacity`` int32 keys.
+    Fat layout (``node_width`` > 1) adds the ``fat_keys`` run tile
+    (``capacity * node_width`` int32) — ``capacity`` counts NODE slots
+    there, so for a fixed element count the skip tables shrink by the
+    fill factor while the run tile holds the elements themselves
+    (``fat_vals`` never ships to a kernel; values resolve outside).
+    The lane-dense planes the kernels take add no padding past a row of
+    128, so these are also the bytes Mosaic allocates per tile buffer;
+    ``tests/test_tpu_compile.py`` holds the budget to the v5e compiler.
+    """
+    base = (levels * capacity * 2 * 4 if foresight
+            else levels * capacity * 4 + capacity * 4)
+    if node_width > 1:
+        base += capacity * node_width * 4
+    return base
 
 
 # ---------------------------------------------------------------------------
@@ -197,112 +228,260 @@ def plan_launch(*, levels: int, capacity: int, batch: int,
     return LaunchPlan(grid, nblk, max_steps)
 
 
-def _fused_gather(fused_tile, cap: int):
-    """ONE VMEM gather per step: the (ptr, key) record, pair-atomic by layout."""
-    flat_ptr = fused_tile[..., 0].reshape(-1)
-    flat_key = fused_tile[..., 1].reshape(-1)
+def interpret_mode() -> bool:
+    """Whether the Pallas kernels run interpreted: on the CPU backend only.
+
+    The ONE place the choice is made.  On a TPU every ``pallas_call`` is
+    compiled by Mosaic; any other backend has no kernel path and raises
+    rather than silently swapping the chip for the interpreter.
+    """
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(f"no Pallas kernel path on backend {backend!r}: "
+                       "kernels compile on TPU and run interpreted on CPU")
+
+
+# ---------------------------------------------------------------------------
+# Lane-dense tables and the per-lane row gather
+# ---------------------------------------------------------------------------
+#
+# Mosaic lowers no gather from a large VMEM table (only in-vreg 2-D
+# gathers), so every table reaches a kernel as a row-major lane-dense
+# PLANE ``[rows, 128]``.  A gather of one element per query lane is then:
+# the 128 row indices go VMEM -> SMEM, a scalar loop copies each lane's
+# row into a ``[QBLK, 128]`` scratch (one dynamic row load per lane), and
+# one transpose + masked sublane sum selects each lane's element
+# (``_pick``).  The fused plane (``_fused_plane``) is the TPU's own
+# ``(2, 128)`` tiling of ``fused [L, cap, 2]``: per 128-node chunk a row of
+# pointers, then the row of their foreseen keys.  Both halves of a record
+# sit in the SAME tile, so the foresight step still costs one dependent
+# tile gather, the base step two; and the plane is a free reinterpretation
+# of the stored table, not a relayout.
+
+ROW = 128                        # lanes per plane row
+_UNROLL = 8                      # row loads per row-gather loop iteration
+
+
+def _plane(a: jax.Array, lead: int = 0) -> jax.Array:
+    """``a`` flattened after its first ``lead`` axes into ``[..., rows, 128]``
+    (row-major, zero-padded to a whole row)."""
+    head = a.shape[:lead]
+    flat = a.reshape(head + (-1,))
+    pad = (-flat.shape[-1]) % ROW
+    if pad:
+        flat = jnp.pad(flat, [(0, 0)] * lead + [(0, pad)])
+    return flat.reshape(head + (-1, ROW))
+
+
+def _row_padded(n: int) -> int:
+    return n + (-n) % ROW
+
+
+def _fused_plane(fused: jax.Array, lead: int = 0) -> jax.Array:
+    """``fused [..., L, cap, 2]`` as ``[..., L * cap_p // 64, 128]``: for the
+    128-node chunk ``c`` of level ``l`` (``cap`` zero-padded to ``cap_p``, a
+    multiple of 128), row ``2 * (l * cap_p // 128 + c)`` holds the pointers
+    and the next row their foreseen keys.  This is the order the TPU keeps
+    the table in (layout ``T(2, 128)``), so no copy is made."""
+    *head, L, cap, _ = fused.shape
+    cap_p = _row_padded(cap)
+    if cap_p > cap:
+        fused = jnp.pad(fused, [(0, 0)] * (lead + 1) + [(0, cap_p - cap),
+                                                        (0, 0)])
+    t = fused.reshape(*head, L, cap_p // ROW, ROW, 2)
+    return jnp.swapaxes(t, -1, -2).reshape(*head, -1, ROW)
+
+
+def _row_scratch():
+    """Scratch of one per-lane row gather: row ids in VMEM and SMEM, and
+    two ``[QBLK, 128]`` buffers the rows land in."""
+    return [pltpu.VMEM((1, QBLK), jnp.int32),
+            pltpu.SMEM((1, QBLK), jnp.int32),
+            pltpu.VMEM((QBLK, ROW), jnp.int32),
+            pltpu.VMEM((QBLK, ROW), jnp.int32)]
+
+
+def _gather_rows(tab_ref, row, scratch, n: int = 1):
+    """ONE dependent gather of ``n`` adjacent rows per lane: returns
+    ``[T_0, ...]`` with ``T_k[:, i] = tab[row[i] + k, :]`` (``[128, QBLK]``)."""
+    idx_v, idx_s, *bufs = scratch
+    idx_v[...] = row
+    pltpu.sync_copy(idx_v, idx_s)
+
+    def body(i, carry):
+        # unrolled by hand: Mosaic takes no partial ``unroll=`` here
+        for u in range(_UNROLL):
+            r = i * _UNROLL + u
+            src = idx_s[0, r]
+            for k in range(n):
+                bufs[k][pl.ds(r, 1), :] = tab_ref[pl.ds(src + k, 1), :]
+        return carry
+
+    lax.fori_loop(0, QBLK // _UNROLL, body, 0)
+    return [b[...].T for b in bufs[:n]]
+
+
+def _pick(rows_t, col):
+    """In-row lane select: lane ``i`` of the result is ``rows_t[col[i], i]``."""
+    j = lax.broadcasted_iota(jnp.int32, rows_t.shape, 0)
+    return jnp.sum(jnp.where(j == col, rows_t, 0), axis=0, keepdims=True)
+
+
+def _fused_gather(fused_ref, cap: int, scratch):
+    """ONE tile gather per step: the (ptr, key) record, pair-atomic by
+    layout (``cap`` is the plane's padded per-level node count)."""
 
     def gather(lvl, x):
-        idx = lvl * cap + x
-        return (jnp.take(flat_ptr, idx, axis=0),     # ┐ one fused VMEM gather
-                jnp.take(flat_key, idx, axis=0))     # ┘ (same record, 2 lanes)
+        row = 2 * (lvl * (cap // ROW) + (x >> 7))
+        ptr_t, key_t = _gather_rows(fused_ref, row, scratch, 2)  # one tile
+        col = x & (ROW - 1)
+        return _pick(ptr_t, col), _pick(key_t, col)
     return gather
 
 
-def _base_gather(nxt_tile, keys_tile, cap: int):
-    """TWO chained gathers per step: pointer, then pointee key — DEPENDENT."""
-    nxt = nxt_tile.reshape(-1)                       # [L*cap]
-    keys = keys_tile.reshape(-1)                     # [cap]
+def _base_gather(nxt_ref, keys_ref, cap: int, scratch):
+    """TWO chained row gathers per step: pointer, then pointee key."""
 
     def gather(lvl, x):
-        ptr = jnp.take(nxt, lvl * cap + x, axis=0)   # gather 1
-        return ptr, jnp.take(keys, ptr, axis=0)      # gather 2 — DEPENDENT
+        e = lvl * cap + x
+        ptr = _pick(_gather_rows(nxt_ref, e >> 7, scratch)[0], e & (ROW - 1))
+        key = _pick(_gather_rows(keys_ref, ptr >> 7, scratch)[0],  # DEPENDENT
+                    ptr & (ROW - 1))
+        return ptr, key
     return gather
 
 
-def _fat_resolve(gather, fat_keys, q, x, node_width: int):
-    """Fat-node postlude: one tile gather + lane-wide compare land on the
+def _fat_resolve(gather, fatk_ref, q, x, node_width: int, scratch):
+    """Fat-node postlude: one run gather + lane-wide compare land on the
     element.
 
     ``x`` is the node-level predecessor; its level-0 successor ``cand`` is
     the candidate node.  The query lives in ``cand``'s run when the
     foreseen min-key equals it exactly (runs carry their minimum as the
     routing key) or when the predecessor is the head sentinel; otherwise
-    it lies inside ``x``'s own run.  ONE gather pulls the owner's whole
-    ``node_width`` run into VREGs; the lane-wide ``<`` count is the
-    searchsorted position.  Returns an ELEMENT-flat node id
-    (``owner * node_width + pos``) and the key at that position
-    (``KEY_MAX`` when the query exceeds the whole run) so the caller's
-    ``key == q`` found-test is layout-independent.
+    it lies inside ``x``'s own run.  ONE row gather pulls the owner's
+    whole ``node_width`` run (``node_width`` divides 128, so a run never
+    straddles a plane row); the in-run ``<`` count is the searchsorted
+    position.  Returns an ELEMENT-flat node id (``owner * node_width +
+    pos``) and the key at that position (``KEY_MAX`` when the query
+    exceeds the whole run) so the caller's ``key == q`` found-test is
+    layout-independent.
     """
     cand, ck = gather(jnp.zeros_like(q), x)
     owner = jnp.where((ck == q) | (x == 0), cand, x)
-    lane = lax.broadcasted_iota(jnp.int32, (q.shape[0], node_width), 1)
-    run = jnp.take(fat_keys.reshape(-1),
-                   owner[:, None] * node_width + lane, axis=0)
-    pos = jnp.sum((run < q[:, None]).astype(jnp.int32), axis=1)
+    start = owner * node_width
+    rows_t = _gather_rows(fatk_ref, start >> 7, scratch)[0]
+    off = start & (ROW - 1)
+    j = lax.broadcasted_iota(jnp.int32, rows_t.shape, 0)
+    in_run = (j >= off) & (j < off + node_width)
+    pos = jnp.sum((in_run & (rows_t < q)).astype(jnp.int32), axis=0,
+                  keepdims=True)
     pos_c = jnp.minimum(pos, node_width - 1)
-    hit = jnp.sum(jnp.where(lane == pos_c[:, None], run, 0), axis=1)
+    hit = _pick(rows_t, off + pos_c)
     key = jnp.where(pos < node_width, hit, jnp.int32(_KEY_MAX))
-    return owner * node_width + pos_c, key
+    return start + pos_c, key
+
+
+def _check_node_width(node_width: int) -> None:
+    if ROW % node_width:
+        raise ValueError(f"node_width={node_width} must divide {ROW}: a "
+                         "kernel gathers a fat run from one plane row")
 
 
 # ---------------------------------------------------------------------------
-# Foresight kernel: ONE dependent gather per lock-step iteration
+# Kernel bodies.  Refs: lane blocks are [1, QBLK]; tables are planes
+# [rows, 128]; ``rest`` = ([fat run plane,] node, key, *row-gather scratch)
 # ---------------------------------------------------------------------------
 
-def _foresight_kernel(q_ref, fused_ref, *rest,
-                      levels: int, cap: int, max_steps: int,
-                      node_width: int = 1):
+def _split_rest(rest, node_width: int):
+    io, scratch = list(rest[:-4]), rest[-4:]
+    fatk_ref = io.pop(0) if node_width > 1 else None
+    node_ref, key_ref = io
+    return fatk_ref, node_ref, key_ref, scratch
+
+
+def _finish(gather, fatk_ref, q, x, node_width: int, scratch):
+    """(node, key) of the query's candidate from the final predecessor."""
     if node_width > 1:
-        fatk_ref, node_ref, key_ref = rest
-    else:
-        node_ref, key_ref = rest
-    q = q_ref[...]                                   # [QBLK] int32
-    gather = _fused_gather(fused_ref[...], cap)      # [L, cap, 2] in VMEM
+        return _fat_resolve(gather, fatk_ref, q, x, node_width, scratch)
+    return gather(jnp.zeros_like(q), x)    # level-0 successor = candidate
+
+
+# Foresight kernel: ONE dependent row gather per lock-step iteration
+
+def _foresight_kernel(q_ref, fused_ref, *rest, levels: int, cap: int,
+                      max_steps: int, node_width: int = 1):
+    fatk_ref, node_ref, key_ref, scratch = _split_rest(rest, node_width)
+    q = q_ref[...]                                   # [1, QBLK] int32
+    gather = _fused_gather(fused_ref, cap, scratch)  # fused plane in VMEM
     x = _traverse_loop(q, jnp.ones_like(q, jnp.bool_), gather,
                        levels=levels, max_steps=max_steps)
-    if node_width > 1:
-        node, key = _fat_resolve(gather, fatk_ref[...], q, x, node_width)
-    else:
-        # Level-0 successor of the final predecessor = the candidate.
-        node, key = gather(jnp.zeros_like(q), x)
-    node_ref[...] = node
-    key_ref[...] = key
+    node_ref[...], key_ref[...] = _finish(gather, fatk_ref, q, x,
+                                          node_width, scratch)
 
 
-# ---------------------------------------------------------------------------
-# Base kernel: TWO chained gathers per lock-step iteration
-# ---------------------------------------------------------------------------
+# Base kernel: TWO chained row gathers per lock-step iteration
 
-def _base_kernel(q_ref, nxt_ref, keys_ref, *rest,
-                 levels: int, cap: int, max_steps: int,
-                 node_width: int = 1):
-    if node_width > 1:
-        fatk_ref, node_ref, key_ref = rest
-    else:
-        node_ref, key_ref = rest
+def _base_kernel(q_ref, nxt_ref, keys_ref, *rest, levels: int, cap: int,
+                 max_steps: int, node_width: int = 1):
+    fatk_ref, node_ref, key_ref, scratch = _split_rest(rest, node_width)
     q = q_ref[...]
-    gather = _base_gather(nxt_ref[...], keys_ref[...], cap)
+    gather = _base_gather(nxt_ref, keys_ref, cap, scratch)
     x = _traverse_loop(q, jnp.ones_like(q, jnp.bool_), gather,
                        levels=levels, max_steps=max_steps)
-    if node_width > 1:
-        node, key = _fat_resolve(gather, fatk_ref[...], q, x, node_width)
-    else:
-        node, key = gather(jnp.zeros_like(q), x)
-    node_ref[...] = node
-    key_ref[...] = key
+    node_ref[...], key_ref[...] = _finish(gather, fatk_ref, q, x,
+                                          node_width, scratch)
 
 
 # ---------------------------------------------------------------------------
-# pallas_call wrappers with explicit BlockSpec VMEM tiling
+# pallas_call plumbing shared by every wrapper
 # ---------------------------------------------------------------------------
+
+_LANE_SPEC = pl.BlockSpec((None, 1, QBLK), lambda j, *_: (j, 0, 0))
+
+
+def _lanes(a: jax.Array) -> jax.Array:
+    """[B] -> [B // QBLK, 1, QBLK]: one lane-dense block per grid row."""
+    return a.astype(jnp.int32).reshape(-1, 1, QBLK)
+
+
+def _launch(kernel, grid, in_specs, operands, *, interpret: bool,
+            num_scalar_prefetch: int = 0):
+    """One ``pallas_call`` returning (node[B], cand_key[B])."""
+    nblk = grid[0]
+    out = jax.ShapeDtypeStruct((nblk, 1, QBLK), jnp.int32)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=num_scalar_prefetch, grid=grid,
+        in_specs=in_specs, out_specs=[_LANE_SPEC, _LANE_SPEC],
+        scratch_shapes=_row_scratch())
+    node, key = pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=[out, out],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret)(*operands)
+    return node.reshape(-1), key.reshape(-1)
+
+
+def _with_fat(in_specs, operands, fat_keys, index_map, lead: int):
+    """Append the fat run plane (when present); returns node_width."""
+    if fat_keys is None:
+        return 1
+    nw = fat_keys.shape[-1]
+    _check_node_width(nw)
+    fat_p = _plane(fat_keys, lead)
+    block = ((None,) * lead) + fat_p.shape[lead:]
+    in_specs.append(pl.BlockSpec(block, index_map))
+    operands.append(fat_p)
+    return nw
 
 
 @functools.partial(jax.jit, static_argnames=("max_steps", "interpret"))
 def foresight_traverse(fused: jax.Array, queries: jax.Array,
                        fat_keys: Optional[jax.Array] = None, *,
-                       max_steps: int = 0, interpret: bool = True):
+                       max_steps: int = 0, interpret: bool):
     """Batched foresight search. Returns (node[B], cand_key[B]).
 
     ``queries`` length must be a multiple of QBLK (ops.py pads).  With
@@ -310,34 +489,36 @@ def foresight_traverse(fused: jax.Array, queries: jax.Array,
     (``owner * node_width + pos``, see ``_fat_resolve``).
     """
     L, cap, _ = fused.shape
-    B = queries.shape[0]
-    plan = plan_launch(levels=L, capacity=cap, batch=B, max_steps=max_steps)
-    nw = 1 if fat_keys is None else fat_keys.shape[-1]
-    kernel = functools.partial(_foresight_kernel, levels=L, cap=cap,
+    plan = plan_launch(levels=L, capacity=cap, batch=queries.shape[0],
+                       max_steps=max_steps)
+    tab = _fused_plane(fused)
+    in_specs = [_LANE_SPEC, pl.BlockSpec(tab.shape, lambda *_: (0, 0))]
+    operands = [_lanes(queries), tab]
+    nw = _with_fat(in_specs, operands, fat_keys, lambda *_: (0, 0), 0)
+    kernel = functools.partial(_foresight_kernel, levels=L,
+                               cap=_row_padded(cap),
                                max_steps=plan.max_steps, node_width=nw)
-    in_specs = [
-        pl.BlockSpec((QBLK,), lambda i: (i,)),          # queries → VMEM
-        pl.BlockSpec((L, cap, 2), lambda i: (0, 0, 0)),  # fused table → VMEM
-    ]
-    operands = [queries.astype(jnp.int32), fused]
-    if nw > 1:
-        in_specs.append(pl.BlockSpec((cap, nw), lambda i: (0, 0)))
-        operands.append(fat_keys)
-    node, key = pl.pallas_call(
-        kernel,
-        grid=plan.grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((QBLK,), lambda i: (i,)),
-            pl.BlockSpec((QBLK,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(*operands)
-    return node, key
+    return _launch(kernel, plan.grid, in_specs, operands,
+                   interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("max_steps", "interpret"))
+def base_traverse(nxt: jax.Array, keys: jax.Array, queries: jax.Array,
+                  fat_keys: Optional[jax.Array] = None, *,
+                  max_steps: int = 0, interpret: bool):
+    """Batched base (no-foresight) search. Returns (node[B], cand_key[B])."""
+    L, cap = nxt.shape
+    plan = plan_launch(levels=L, capacity=cap, batch=queries.shape[0],
+                       max_steps=max_steps)
+    nxt_p, keys_p = _plane(nxt), _plane(keys)
+    in_specs = [_LANE_SPEC, pl.BlockSpec(nxt_p.shape, lambda *_: (0, 0)),
+                pl.BlockSpec(keys_p.shape, lambda *_: (0, 0))]
+    operands = [_lanes(queries), nxt_p, keys_p]
+    nw = _with_fat(in_specs, operands, fat_keys, lambda *_: (0, 0), 0)
+    kernel = functools.partial(_base_kernel, levels=L, cap=cap,
+                               max_steps=plan.max_steps, node_width=nw)
+    return _launch(kernel, plan.grid, in_specs, operands,
+                   interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -349,74 +530,67 @@ def foresight_traverse(fused: jax.Array, queries: jax.Array,
 # visited consecutively and the output block stays resident in VMEM across
 # them (the standard revisited-block accumulation pattern): we initialize at
 # s == 0 and each shard masks in the lanes it owns (sid == s).  BlockSpec
-# ``lambda j, s: (s, 0, 0, 0)`` pins exactly one per-shard table tile —
+# ``lambda j, s: (s, 0, 0)`` pins exactly one per-shard table plane —
 # sized under VMEM_BUDGET_BYTES by the builder — per grid step, which is
 # precisely the sharded key-space path the module docstring promises.
 # Shard tiles with no routed lanes skip the traversal loop via pl.when.
 
+def _serve_mine(mine, first, live, node_ref, key_ref, search):
+    """Masked revisited-block update shared by the sharded kernels: zero
+    the outputs at the first minor step, then (when ``live``) write
+    ``search()``'s results into the lanes this tile owns."""
+
+    @pl.when(first)
+    def _init():
+        node_ref[...] = jnp.zeros_like(mine, jnp.int32)
+        key_ref[...] = jnp.zeros_like(mine, jnp.int32)
+
+    @pl.when(live)
+    def _traverse():
+        node, key = search()
+        node_ref[...] = jnp.where(mine, node, node_ref[...])
+        key_ref[...] = jnp.where(mine, key, key_ref[...])
+
+
 def _foresight_sharded_kernel(q_ref, sid_ref, fused_ref, *rest,
                               levels: int, cap: int, max_steps: int,
                               node_width: int = 1):
-    if node_width > 1:
-        fatk_ref, node_ref, key_ref = rest
-    else:
-        node_ref, key_ref = rest
+    fatk_ref, node_ref, key_ref, scratch = _split_rest(rest, node_width)
     s = pl.program_id(1)
-    q = q_ref[...]                                   # [QBLK] int32
+    q = q_ref[...]                                   # [1, QBLK] int32
     mine = sid_ref[...] == s                         # lanes routed to tile s
 
-    @pl.when(s == 0)
-    def _init():
-        node_ref[...] = jnp.zeros_like(q)
-        key_ref[...] = jnp.zeros_like(q)
-
-    @pl.when(jnp.any(mine))
-    def _traverse():
-        gather = _fused_gather(fused_ref[...], cap)  # [1, L, cap, 2] tile
+    def search():
+        gather = _fused_gather(fused_ref, cap, scratch)
         x = _traverse_loop(q, mine, gather, levels=levels,
                            max_steps=max_steps)
-        if node_width > 1:
-            node, key = _fat_resolve(gather, fatk_ref[...], q, x, node_width)
-        else:
-            node, key = gather(jnp.zeros_like(q), x)
-        node_ref[...] = jnp.where(mine, node, node_ref[...])
-        key_ref[...] = jnp.where(mine, key, key_ref[...])
+        return _finish(gather, fatk_ref, q, x, node_width, scratch)
+
+    _serve_mine(mine, s == 0, jnp.any(mine), node_ref, key_ref, search)
 
 
 def _base_sharded_kernel(q_ref, sid_ref, nxt_ref, keys_ref, *rest,
                          levels: int, cap: int, max_steps: int,
                          node_width: int = 1):
-    if node_width > 1:
-        fatk_ref, node_ref, key_ref = rest
-    else:
-        node_ref, key_ref = rest
+    fatk_ref, node_ref, key_ref, scratch = _split_rest(rest, node_width)
     s = pl.program_id(1)
     q = q_ref[...]
     mine = sid_ref[...] == s
 
-    @pl.when(s == 0)
-    def _init():
-        node_ref[...] = jnp.zeros_like(q)
-        key_ref[...] = jnp.zeros_like(q)
-
-    @pl.when(jnp.any(mine))
-    def _traverse():
-        gather = _base_gather(nxt_ref[...], keys_ref[...], cap)
+    def search():
+        gather = _base_gather(nxt_ref, keys_ref, cap, scratch)
         x = _traverse_loop(q, mine, gather, levels=levels,
                            max_steps=max_steps)
-        if node_width > 1:
-            node, key = _fat_resolve(gather, fatk_ref[...], q, x, node_width)
-        else:
-            node, key = gather(jnp.zeros_like(q), x)
-        node_ref[...] = jnp.where(mine, node, node_ref[...])
-        key_ref[...] = jnp.where(mine, key, key_ref[...])
+        return _finish(gather, fatk_ref, q, x, node_width, scratch)
+
+    _serve_mine(mine, s == 0, jnp.any(mine), node_ref, key_ref, search)
 
 
 @functools.partial(jax.jit, static_argnames=("max_steps", "interpret"))
 def foresight_traverse_sharded(fused: jax.Array, shard_ids: jax.Array,
                                queries: jax.Array,
                                fat_keys: Optional[jax.Array] = None, *,
-                               max_steps: int = 0, interpret: bool = True):
+                               max_steps: int = 0, interpret: bool):
     """Sharded foresight search over stacked tables ``fused [S, L, cap, 2]``.
 
     ``shard_ids [B]`` routes each (padded) query lane to its key-range shard
@@ -425,78 +599,41 @@ def foresight_traverse_sharded(fused: jax.Array, shard_ids: jax.Array,
     [S, cap, node_width]``).
     """
     S, L, cap, _ = fused.shape
-    B = queries.shape[0]
-    plan = plan_launch(levels=L, capacity=cap, batch=B,
+    plan = plan_launch(levels=L, capacity=cap, batch=queries.shape[0],
                        max_steps=max_steps, n_shards=S)
-    nw = 1 if fat_keys is None else fat_keys.shape[-1]
-    kernel = functools.partial(_foresight_sharded_kernel, levels=L, cap=cap,
+    tab = _fused_plane(fused, 1)
+    tile = lambda j, s: (s, 0, 0)                    # noqa: E731
+    in_specs = [_LANE_SPEC, _LANE_SPEC,
+                pl.BlockSpec((None,) + tab.shape[1:], tile)]
+    operands = [_lanes(queries), _lanes(shard_ids), tab]
+    nw = _with_fat(in_specs, operands, fat_keys, tile, 1)
+    kernel = functools.partial(_foresight_sharded_kernel, levels=L,
+                               cap=_row_padded(cap),
                                max_steps=plan.max_steps, node_width=nw)
-    in_specs = [
-        pl.BlockSpec((QBLK,), lambda j, s: (j,)),        # queries → VMEM
-        pl.BlockSpec((QBLK,), lambda j, s: (j,)),        # shard ids
-        pl.BlockSpec((1, L, cap, 2), lambda j, s: (s, 0, 0, 0)),  # tile s
-    ]
-    operands = [queries.astype(jnp.int32), shard_ids.astype(jnp.int32),
-                fused]
-    if nw > 1:
-        in_specs.append(pl.BlockSpec((1, cap, nw), lambda j, s: (s, 0, 0)))
-        operands.append(fat_keys)
-    node, key = pl.pallas_call(
-        kernel,
-        grid=plan.grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((QBLK,), lambda j, s: (j,)),
-            pl.BlockSpec((QBLK,), lambda j, s: (j,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(*operands)
-    return node, key
+    return _launch(kernel, plan.grid, in_specs, operands,
+                   interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("max_steps", "interpret"))
 def base_traverse_sharded(nxt: jax.Array, keys: jax.Array,
                           shard_ids: jax.Array, queries: jax.Array,
                           fat_keys: Optional[jax.Array] = None, *,
-                          max_steps: int = 0, interpret: bool = True):
+                          max_steps: int = 0, interpret: bool):
     """Sharded base search over ``nxt [S, L, cap]`` / ``keys [S, cap]``."""
     S, L, cap = nxt.shape
-    B = queries.shape[0]
-    plan = plan_launch(levels=L, capacity=cap, batch=B,
+    plan = plan_launch(levels=L, capacity=cap, batch=queries.shape[0],
                        max_steps=max_steps, n_shards=S)
-    nw = 1 if fat_keys is None else fat_keys.shape[-1]
+    nxt_p, keys_p = _plane(nxt, 1), _plane(keys, 1)
+    tile = lambda j, s: (s, 0, 0)                    # noqa: E731
+    in_specs = [_LANE_SPEC, _LANE_SPEC,
+                pl.BlockSpec((None,) + nxt_p.shape[1:], tile),
+                pl.BlockSpec((None,) + keys_p.shape[1:], tile)]
+    operands = [_lanes(queries), _lanes(shard_ids), nxt_p, keys_p]
+    nw = _with_fat(in_specs, operands, fat_keys, tile, 1)
     kernel = functools.partial(_base_sharded_kernel, levels=L, cap=cap,
                                max_steps=plan.max_steps, node_width=nw)
-    in_specs = [
-        pl.BlockSpec((QBLK,), lambda j, s: (j,)),
-        pl.BlockSpec((QBLK,), lambda j, s: (j,)),
-        pl.BlockSpec((1, L, cap), lambda j, s: (s, 0, 0)),
-        pl.BlockSpec((1, cap), lambda j, s: (s, 0)),
-    ]
-    operands = [queries.astype(jnp.int32), shard_ids.astype(jnp.int32),
-                nxt, keys]
-    if nw > 1:
-        in_specs.append(pl.BlockSpec((1, cap, nw), lambda j, s: (s, 0, 0)))
-        operands.append(fat_keys)
-    node, key = pl.pallas_call(
-        kernel,
-        grid=plan.grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((QBLK,), lambda j, s: (j,)),
-            pl.BlockSpec((QBLK,), lambda j, s: (j,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(*operands)
-    return node, key
+    return _launch(kernel, plan.grid, in_specs, operands,
+                   interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -523,62 +660,46 @@ def _foresight_clustered_kernel(bsids_ref, ndist_ref, q_ref, sid_ref,
                                 fused_ref, *rest,
                                 levels: int, cap: int, max_steps: int,
                                 node_width: int = 1):
-    if node_width > 1:
-        fatk_ref, node_ref, key_ref = rest
-    else:
-        node_ref, key_ref = rest
+    fatk_ref, node_ref, key_ref, scratch = _split_rest(rest, node_width)
     j = pl.program_id(0)
     k = pl.program_id(1)
-    q = q_ref[...]                                   # [QBLK] shard-sorted
+    q = q_ref[...]                                   # [1, QBLK] shard-sorted
     mine = sid_ref[...] == bsids_ref[j, k]
 
-    @pl.when(k == 0)
-    def _init():
-        node_ref[...] = jnp.zeros_like(q)
-        key_ref[...] = jnp.zeros_like(q)
-
-    @pl.when(k < ndist_ref[j])
-    def _traverse():
-        gather = _fused_gather(fused_ref[...], cap)  # [1, L, cap, 2] tile
+    def search():
+        gather = _fused_gather(fused_ref, cap, scratch)
         x = _traverse_loop(q, mine, gather, levels=levels,
                            max_steps=max_steps)
-        if node_width > 1:
-            node, key = _fat_resolve(gather, fatk_ref[...], q, x, node_width)
-        else:
-            node, key = gather(jnp.zeros_like(q), x)
-        node_ref[...] = jnp.where(mine, node, node_ref[...])
-        key_ref[...] = jnp.where(mine, key, key_ref[...])
+        return _finish(gather, fatk_ref, q, x, node_width, scratch)
+
+    _serve_mine(mine, k == 0, k < ndist_ref[j], node_ref, key_ref, search)
 
 
 def _base_clustered_kernel(bsids_ref, ndist_ref, q_ref, sid_ref, nxt_ref,
                            keys_ref, *rest,
                            levels: int, cap: int, max_steps: int,
                            node_width: int = 1):
-    if node_width > 1:
-        fatk_ref, node_ref, key_ref = rest
-    else:
-        node_ref, key_ref = rest
+    fatk_ref, node_ref, key_ref, scratch = _split_rest(rest, node_width)
     j = pl.program_id(0)
     k = pl.program_id(1)
     q = q_ref[...]
     mine = sid_ref[...] == bsids_ref[j, k]
 
-    @pl.when(k == 0)
-    def _init():
-        node_ref[...] = jnp.zeros_like(q)
-        key_ref[...] = jnp.zeros_like(q)
-
-    @pl.when(k < ndist_ref[j])
-    def _traverse():
-        gather = _base_gather(nxt_ref[...], keys_ref[...], cap)
+    def search():
+        gather = _base_gather(nxt_ref, keys_ref, cap, scratch)
         x = _traverse_loop(q, mine, gather, levels=levels,
                            max_steps=max_steps)
-        if node_width > 1:
-            node, key = _fat_resolve(gather, fatk_ref[...], q, x, node_width)
-        else:
-            node, key = gather(jnp.zeros_like(q), x)
-        node_ref[...] = jnp.where(mine, node, node_ref[...])
-        key_ref[...] = jnp.where(mine, key, key_ref[...])
+        return _finish(gather, fatk_ref, q, x, node_width, scratch)
+
+    _serve_mine(mine, k == 0, k < ndist_ref[j], node_ref, key_ref, search)
+
+
+def _check_plan(B: int, block_sids: jax.Array, S: int) -> None:
+    nblk, K = block_sids.shape
+    assert B == nblk * QBLK, "queries must be padded to block_sids' blocks"
+    assert K <= S, (f"ClusterPlan with K={K} > S={S}: plan built against a "
+                    "different shard count (stale after a rebalance?) — "
+                    "rebuild it from the current boundaries")
 
 
 @functools.partial(jax.jit, static_argnames=("max_steps", "interpret"))
@@ -586,7 +707,7 @@ def foresight_traverse_clustered(fused: jax.Array, block_sids: jax.Array,
                                  ndist: jax.Array, shard_ids: jax.Array,
                                  queries: jax.Array,
                                  fat_keys: Optional[jax.Array] = None, *,
-                                 max_steps: int = 0, interpret: bool = True):
+                                 max_steps: int = 0, interpret: bool):
     """Clustered foresight search over ``fused [S, L, cap, 2]``.
 
     ``queries``/``shard_ids`` must be shard-sorted and ``block_sids [nblk,
@@ -596,49 +717,21 @@ def foresight_traverse_clustered(fused: jax.Array, block_sids: jax.Array,
     """
     S, L, cap, _ = fused.shape
     B = queries.shape[0]
-    nblk, K = block_sids.shape
-    assert B == nblk * QBLK, "queries must be padded to block_sids' blocks"
-    assert K <= S, (f"ClusterPlan with K={K} > S={S}: plan built against a "
-                    "different shard count (stale after a rebalance?) — "
-                    "rebuild it from the current boundaries")
+    _check_plan(B, block_sids, S)
     plan = plan_launch(levels=L, capacity=cap, batch=B,
-                       max_steps=max_steps, n_shards=K)
-    nw = 1 if fat_keys is None else fat_keys.shape[-1]
-    kernel = functools.partial(_foresight_clustered_kernel, levels=L,
-                               cap=cap, max_steps=plan.max_steps,
-                               node_width=nw)
-    in_specs = [
-        pl.BlockSpec((QBLK,), lambda j, k, bs, nd: (j,)),
-        pl.BlockSpec((QBLK,), lambda j, k, bs, nd: (j,)),
-        pl.BlockSpec((1, L, cap, 2),
-                     lambda j, k, bs, nd: (bs[j, k], 0, 0, 0)),
-    ]
+                       max_steps=max_steps, n_shards=block_sids.shape[1])
+    tab = _fused_plane(fused, 1)
+    tile = lambda j, k, bs, nd: (bs[j, k], 0, 0)     # noqa: E731
+    in_specs = [_LANE_SPEC, _LANE_SPEC,
+                pl.BlockSpec((None,) + tab.shape[1:], tile)]
     operands = [block_sids.astype(jnp.int32), ndist.astype(jnp.int32),
-                queries.astype(jnp.int32), shard_ids.astype(jnp.int32),
-                fused]
-    if nw > 1:
-        in_specs.append(pl.BlockSpec((1, cap, nw),
-                                     lambda j, k, bs, nd: (bs[j, k], 0, 0)))
-        operands.append(fat_keys)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=plan.grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((QBLK,), lambda j, k, bs, nd: (j,)),
-            pl.BlockSpec((QBLK,), lambda j, k, bs, nd: (j,)),
-        ],
-    )
-    node, key = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(*operands)
-    return node, key
+                _lanes(queries), _lanes(shard_ids), tab]
+    nw = _with_fat(in_specs, operands, fat_keys, tile, 1)
+    kernel = functools.partial(_foresight_clustered_kernel, levels=L,
+                               cap=_row_padded(cap), max_steps=plan.max_steps,
+                               node_width=nw)
+    return _launch(kernel, plan.grid, in_specs, operands,
+                   interpret=interpret, num_scalar_prefetch=2)
 
 
 @functools.partial(jax.jit, static_argnames=("max_steps", "interpret"))
@@ -646,86 +739,22 @@ def base_traverse_clustered(nxt: jax.Array, keys: jax.Array,
                             block_sids: jax.Array, ndist: jax.Array,
                             shard_ids: jax.Array, queries: jax.Array,
                             fat_keys: Optional[jax.Array] = None, *,
-                            max_steps: int = 0, interpret: bool = True):
+                            max_steps: int = 0, interpret: bool):
     """Clustered base search over ``nxt [S, L, cap]`` / ``keys [S, cap]``."""
     S, L, cap = nxt.shape
     B = queries.shape[0]
-    nblk, K = block_sids.shape
-    assert B == nblk * QBLK, "queries must be padded to block_sids' blocks"
-    assert K <= S, (f"ClusterPlan with K={K} > S={S}: plan built against a "
-                    "different shard count (stale after a rebalance?) — "
-                    "rebuild it from the current boundaries")
+    _check_plan(B, block_sids, S)
     plan = plan_launch(levels=L, capacity=cap, batch=B,
-                       max_steps=max_steps, n_shards=K)
-    nw = 1 if fat_keys is None else fat_keys.shape[-1]
+                       max_steps=max_steps, n_shards=block_sids.shape[1])
+    nxt_p, keys_p = _plane(nxt, 1), _plane(keys, 1)
+    tile = lambda j, k, bs, nd: (bs[j, k], 0, 0)     # noqa: E731
+    in_specs = [_LANE_SPEC, _LANE_SPEC,
+                pl.BlockSpec((None,) + nxt_p.shape[1:], tile),
+                pl.BlockSpec((None,) + keys_p.shape[1:], tile)]
+    operands = [block_sids.astype(jnp.int32), ndist.astype(jnp.int32),
+                _lanes(queries), _lanes(shard_ids), nxt_p, keys_p]
+    nw = _with_fat(in_specs, operands, fat_keys, tile, 1)
     kernel = functools.partial(_base_clustered_kernel, levels=L, cap=cap,
                                max_steps=plan.max_steps, node_width=nw)
-    in_specs = [
-        pl.BlockSpec((QBLK,), lambda j, k, bs, nd: (j,)),
-        pl.BlockSpec((QBLK,), lambda j, k, bs, nd: (j,)),
-        pl.BlockSpec((1, L, cap), lambda j, k, bs, nd: (bs[j, k], 0, 0)),
-        pl.BlockSpec((1, cap), lambda j, k, bs, nd: (bs[j, k], 0)),
-    ]
-    operands = [block_sids.astype(jnp.int32), ndist.astype(jnp.int32),
-                queries.astype(jnp.int32), shard_ids.astype(jnp.int32),
-                nxt, keys]
-    if nw > 1:
-        in_specs.append(pl.BlockSpec((1, cap, nw),
-                                     lambda j, k, bs, nd: (bs[j, k], 0, 0)))
-        operands.append(fat_keys)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=plan.grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((QBLK,), lambda j, k, bs, nd: (j,)),
-            pl.BlockSpec((QBLK,), lambda j, k, bs, nd: (j,)),
-        ],
-    )
-    node, key = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(*operands)
-    return node, key
-
-
-@functools.partial(jax.jit, static_argnames=("max_steps", "interpret"))
-def base_traverse(nxt: jax.Array, keys: jax.Array, queries: jax.Array,
-                  fat_keys: Optional[jax.Array] = None, *,
-                  max_steps: int = 0, interpret: bool = True):
-    """Batched base (no-foresight) search. Returns (node[B], cand_key[B])."""
-    L, cap = nxt.shape
-    B = queries.shape[0]
-    plan = plan_launch(levels=L, capacity=cap, batch=B, max_steps=max_steps)
-    nw = 1 if fat_keys is None else fat_keys.shape[-1]
-    kernel = functools.partial(_base_kernel, levels=L, cap=cap,
-                               max_steps=plan.max_steps, node_width=nw)
-    in_specs = [
-        pl.BlockSpec((QBLK,), lambda i: (i,)),
-        pl.BlockSpec((L, cap), lambda i: (0, 0)),
-        pl.BlockSpec((cap,), lambda i: (0,)),
-    ]
-    operands = [queries.astype(jnp.int32), nxt, keys]
-    if nw > 1:
-        in_specs.append(pl.BlockSpec((cap, nw), lambda i: (0, 0)))
-        operands.append(fat_keys)
-    node, key = pl.pallas_call(
-        kernel,
-        grid=plan.grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((QBLK,), lambda i: (i,)),
-            pl.BlockSpec((QBLK,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(*operands)
-    return node, key
+    return _launch(kernel, plan.grid, in_specs, operands,
+                   interpret=interpret, num_scalar_prefetch=2)

@@ -7,7 +7,7 @@ private exchange helpers), but step 4 of the data path launches the
 clustered scalar-prefetch ``pallas_call``
 (``kernels.ops.search_kernel_sharded``) on every device's received lanes:
 grid ``(C' // QBLK, K)`` per device, only routed tiles DMA'd, with
-``check_rep=False`` on the ``shard_map`` because Pallas calls carry no
+``check_vma=False`` on the ``shard_map`` because Pallas calls carry no
 replication rule.
 
 ``k_shards`` must be static inside the trace (the clustered grid's K).
@@ -31,7 +31,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 
 from repro.core.mesh_index import (MeshShardedIndex, _chunk, _exchange_back,
                                    _exchange_out, _validate)
@@ -43,7 +42,7 @@ from repro.parallel.sharding import (INDEX_AXIS, index_batch_spec,
 
 
 @functools.lru_cache(maxsize=None)
-def _kernel_search_fn(mesh, k_shards, max_steps, interpret):
+def _kernel_search_fn(mesh, k_shards, max_steps):
     D = int(mesh.shape[INDEX_AXIS])
 
     def body(local, db, q):
@@ -52,8 +51,7 @@ def _kernel_search_fn(mesh, k_shards, max_steps, interpret):
         (rq,), _, perm, starts, did_s = _exchange_out(
             did, (q,), (jnp.int32(0),), D)
         res = search_kernel_sharded(local, rq, max_steps=max_steps,
-                                    interpret=interpret, cluster=True,
-                                    k_shards=k_shards)
+                                    cluster=True, k_shards=k_shards)
         cap = local.shard_capacity
         S = local.n_shards
         nw = local.node_width   # fat ids are element-flat: stride cap * nw
@@ -65,18 +63,18 @@ def _kernel_search_fn(mesh, k_shards, max_steps, interpret):
         node = _exchange_back(gnode, perm, starts, did_s, D)
         return found.astype(jnp.bool_), vals, node
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(index_state_spec(), index_replicated_spec(),
                   index_batch_spec()),
         out_specs=(index_batch_spec(), index_batch_spec(),
                    index_batch_spec()),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(fn)
 
 
 def search_kernel_mesh(mx: MeshShardedIndex, queries: jax.Array, *, mesh,
-                       max_steps: int = 0, interpret: bool = True,
+                       max_steps: int = 0,
                        k_shards: int = 0) -> KernelSearchResult:
     """Kernel-backed mesh search: route, exchange, clustered launch, gather.
 
@@ -91,8 +89,7 @@ def search_kernel_mesh(mx: MeshShardedIndex, queries: jax.Array, *, mesh,
     q = queries.astype(jnp.int32)
     B = q.shape[0]
     (qp,), _ = _chunk((q,), B, D, (jnp.int32(0),))
-    fn = _kernel_search_fn(mesh, int(k_shards), int(max_steps),
-                           bool(interpret))
+    fn = _kernel_search_fn(mesh, int(k_shards), int(max_steps))
     found, vals, node = fn(mx.local, mx.device_boundaries, qp)
     return KernelSearchResult(found[:B], vals[:B], node[:B])
 

@@ -6,9 +6,11 @@ that decides between the single-tile kernel and the sharded-key-space path.
 
 VMEM-budget math
 ----------------
-A TPU core has ~16 MiB of VMEM; we budget ``VMEM_BUDGET_BYTES`` (12 MiB)
-for the index tile, leaving headroom for query/output blocks and compiler
-temporaries.  The single-tile kernels pin the whole table per grid step:
+Each kernel asks Mosaic for ``VMEM_LIMIT_BYTES`` (32 MiB) of scoped VMEM;
+we budget ``VMEM_BUDGET_BYTES`` (12 MiB) for the index tile, which the
+sharded grids double-buffer, leaving headroom for query/output blocks and
+the row-gather scratch.  The single-tile kernels pin the whole table per
+grid step:
 
 * foresight: ``levels * capacity * 2 * 4`` bytes (fused (ptr, key) pairs),
 * base:      ``levels * capacity * 4 + capacity * 4`` bytes (nxt + keys),
@@ -61,18 +63,15 @@ import numpy as np
 from repro.core import sharded as shd
 from repro.core.skiplist import NULL_VAL, SkipListState
 from repro.core.sharded import ShardedSkipList
-from repro.kernels.foresight_traverse import (QBLK, base_traverse,
+from repro.kernels.foresight_traverse import (QBLK, VMEM_BUDGET_BYTES,
+                                              base_traverse,
                                               base_traverse_clustered,
                                               base_traverse_sharded,
                                               foresight_traverse,
                                               foresight_traverse_clustered,
-                                              foresight_traverse_sharded)
+                                              foresight_traverse_sharded,
+                                              interpret_mode, tile_bytes)
 from repro.kernels.ref import encode_float_keys
-
-# the budget constant and tile-footprint formula live in ONE place
-# (analysis.kernel_budget) so the builders here and the static checker
-# cannot drift apart; re-exported under the historical names
-from repro.analysis.kernel_budget import VMEM_BUDGET_BYTES, tile_bytes
 
 MAX_SHARDS = shd.MAX_SHARDS            # one ceiling, shared with core.sharded
 
@@ -349,9 +348,8 @@ def _degenerate_launch(shl: ShardedSkipList, plan: ClusterPlan, split, *,
 
 
 def search_kernel_sharded(shl: ShardedSkipList, queries: jax.Array, *,
-                          max_steps: int = 0, interpret: bool = True,
-                          cluster: bool = True, k_shards: int = 0
-                          ) -> KernelSearchResult:
+                          max_steps: int = 0, cluster: bool = True,
+                          k_shards: int = 0) -> KernelSearchResult:
     """Kernel-backed search over a partitioned index.
 
     ``cluster=True`` (default) launches the scalar-prefetch clustered grid
@@ -385,6 +383,7 @@ def search_kernel_sharded(shl: ShardedSkipList, queries: jax.Array, *,
             f"({vmem_footprint(shl)} > {VMEM_BUDGET_BYTES} bytes); build "
             "with more shards (auto_shards picks the smallest fitting "
             "count) or repack(shl, n_shards=...) the existing index")
+    interpret = interpret_mode()
     q, B = _pad(queries.astype(jnp.int32))
     if cluster:
         try:
@@ -460,8 +459,8 @@ def search_kernel_sharded(shl: ShardedSkipList, queries: jax.Array, *,
 
 def search_kernel(state: Union[SkipListState, ShardedSkipList],
                   queries: jax.Array, *, max_steps: int = 0,
-                  interpret: bool = True, cluster: bool = True,
-                  k_shards: int = 0, mesh=None) -> KernelSearchResult:
+                  cluster: bool = True, k_shards: int = 0,
+                  mesh=None) -> KernelSearchResult:
     """Kernel-backed batched search on any variant; resolves found/vals.
 
     Auto-dispatch: a ``MeshShardedIndex`` takes the mesh-distributed path
@@ -474,6 +473,9 @@ def search_kernel(state: Union[SkipListState, ShardedSkipList],
     now changes boundaries underneath any such cache — callers hold a
     ``ShardedSkipList`` directly instead (``shard_state`` converts once;
     ``core.sharded.build_sharded`` builds one from scratch).
+
+    Kernels compile on a TPU and run interpreted on the CPU backend
+    (``interpret_mode``); any other backend raises.
     """
     from repro.core.mesh_index import MeshShardedIndex
     if isinstance(state, MeshShardedIndex):
@@ -482,18 +484,17 @@ def search_kernel(state: Union[SkipListState, ShardedSkipList],
                              "mesh= (see launch.mesh.make_index_mesh)")
         from repro.kernels.mesh_launch import search_kernel_mesh
         return search_kernel_mesh(state, queries, max_steps=max_steps,
-                                  interpret=interpret, k_shards=k_shards,
-                                  mesh=mesh)
+                                  k_shards=k_shards, mesh=mesh)
     if isinstance(state, ShardedSkipList):
         return search_kernel_sharded(state, queries, max_steps=max_steps,
-                                     interpret=interpret, cluster=cluster,
-                                     k_shards=k_shards)
+                                     cluster=cluster, k_shards=k_shards)
     if not fits_vmem(state):
         raise ValueError(
             "search_kernel: monolithic table exceeds the VMEM budget "
             f"({vmem_footprint(state)} > {VMEM_BUDGET_BYTES} bytes); hold a "
             "ShardedSkipList instead (kernels.ops.shard_state converts a "
             "monolithic state once; core.sharded.build_sharded builds one)")
+    interpret = interpret_mode()
     q, B = _pad(queries.astype(jnp.int32))
     if state.foresight:
         node, ckey = foresight_traverse(state.fused, q, state.fat_keys,
@@ -514,9 +515,9 @@ def search_kernel(state: Union[SkipListState, ShardedSkipList],
 
 def search_kernel_float(state: Union[SkipListState, ShardedSkipList],
                         float_queries: jax.Array, *, max_steps: int = 0,
-                        interpret: bool = True, cluster: bool = True,
+                        cluster: bool = True,
                         k_shards: int = 0) -> KernelSearchResult:
     """Float-keyed search (keys must have been encoded at build time)."""
     return search_kernel(state, encode_float_keys(float_queries),
-                         max_steps=max_steps, interpret=interpret,
-                         cluster=cluster, k_shards=k_shards)
+                         max_steps=max_steps, cluster=cluster,
+                         k_shards=k_shards)

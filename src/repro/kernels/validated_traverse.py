@@ -21,16 +21,20 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from repro.kernels.foresight_traverse import QBLK
+from repro.kernels.foresight_traverse import (QBLK, ROW, _LANE_SPEC,
+                                              _fused_gather, _fused_plane,
+                                              _gather_rows, _lanes, _launch,
+                                              _pick, _plane, _row_padded)
 
 
-def _validated_kernel(q_ref, fused_ref, keys_ref, node_ref, key_ref, *,
-                      levels: int, cap: int, max_steps: int):
-    q = q_ref[...]                                  # [QBLK]
-    tbl = fused_ref[...]
-    flat_ptr = tbl[..., 0].reshape(-1)
-    flat_fk = tbl[..., 1].reshape(-1)
-    auth = keys_ref[...]                            # authoritative keys
+def _validated_kernel(q_ref, fused_ref, keys_ref, node_ref, key_ref,
+                      *scratch, levels: int, cap: int, max_steps: int):
+    q = q_ref[...]                                  # [1, QBLK]
+    fused_gather = _fused_gather(fused_ref, cap, scratch)
+
+    def auth(ptr):                                  # validation gather
+        rows_t = _gather_rows(keys_ref, ptr >> 7, scratch)[0]
+        return _pick(rows_t, ptr & (ROW - 1))
 
     x = jnp.zeros_like(q)
     lvl = jnp.full_like(q, levels - 1)
@@ -39,27 +43,25 @@ def _validated_kernel(q_ref, fused_ref, keys_ref, node_ref, key_ref, *,
         x, lvl = carry
         active = lvl >= 0
         at0 = lvl == 0
-        idx = jnp.maximum(lvl, 0) * cap + x
-        ptr = jnp.take(flat_ptr, idx, axis=0)       # fused gather (pair)
-        fk = jnp.take(flat_fk, idx, axis=0)
-        real = jnp.take(auth, ptr, axis=0)          # validation gather
+        ptr, fk = fused_gather(jnp.maximum(lvl, 0), x)   # fused gather (pair)
+        real = auth(ptr)
         # Alg. 3: upper levels advance iff foreseen AND validated;
         # level 0 trusts only the authoritative key.
-        go = active & jnp.where(at0, real < q, (fk < q) & (real < q))
+        go = active & (real < q) & (at0 | (fk < q))
         x = jnp.where(go, ptr, x)
         lvl = jnp.where(go | ~active, lvl, lvl - 1)
         return x, lvl
 
     x, lvl = lax.fori_loop(0, max_steps, body, (x, lvl))
-    cand = jnp.take(flat_ptr, x, axis=0)            # level-0 successor
+    cand, _ = fused_gather(jnp.zeros_like(q), x)    # level-0 successor
     node_ref[...] = cand
-    key_ref[...] = jnp.take(auth, cand, axis=0)
+    key_ref[...] = auth(cand)
 
 
 @functools.partial(jax.jit, static_argnames=("max_steps", "interpret"))
 def validated_traverse(fused: jax.Array, auth_keys: jax.Array,
                        queries: jax.Array, *, max_steps: int = 0,
-                       interpret: bool = True):
+                       interpret: bool):
     """Batched validated search. Returns (node[B], cand_key[B]).
 
     ``fused`` may carry arbitrarily corrupt foreseen keys; results are
@@ -70,24 +72,10 @@ def validated_traverse(fused: jax.Array, auth_keys: jax.Array,
     assert B % QBLK == 0, "pad queries to a multiple of QBLK"
     if max_steps == 0:
         max_steps = 4 * L + 16
-    kernel = functools.partial(_validated_kernel, levels=L, cap=cap,
-                               max_steps=max_steps)
-    node, key = pl.pallas_call(
-        kernel,
-        grid=(B // QBLK,),
-        in_specs=[
-            pl.BlockSpec((QBLK,), lambda i: (i,)),
-            pl.BlockSpec((L, cap, 2), lambda i: (0, 0, 0)),
-            pl.BlockSpec((cap,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((QBLK,), lambda i: (i,)),
-            pl.BlockSpec((QBLK,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(queries.astype(jnp.int32), fused, auth_keys)
-    return node, key
+    tab, keys_p = _fused_plane(fused), _plane(auth_keys)
+    kernel = functools.partial(_validated_kernel, levels=L,
+                               cap=_row_padded(cap), max_steps=max_steps)
+    in_specs = [_LANE_SPEC, pl.BlockSpec(tab.shape, lambda *_: (0, 0)),
+                pl.BlockSpec(keys_p.shape, lambda *_: (0, 0))]
+    return _launch(kernel, (B // QBLK,), in_specs,
+                   [_lanes(queries), tab, keys_p], interpret=interpret)

@@ -1,4 +1,4 @@
-"""Version-tolerant accessors for jax ``Compiled`` introspection.
+"""Accessors for jax ``Compiled`` introspection.
 
 Import-side-effect free (unlike ``launch.dryrun``, which force-sets the
 virtual device count), so tests and tools can import it after jax init.
@@ -9,18 +9,5 @@ from typing import Any, Dict
 
 
 def cost_dict(compiled) -> Dict[str, Any]:
-    """Normalize ``compiled.cost_analysis()`` across jax versions.
-
-    Older jax returned a plain dict (or ``None`` on some backends); current
-    jax returns a list with one dict per computation.  Returns one flat dict
-    (first computation wins), ``{}`` when analysis is unavailable.
-    """
-    try:
-        cost = compiled.cost_analysis()
-    except Exception:
-        return {}
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if cost else {}
-    return dict(cost)
+    """``compiled.cost_analysis()`` as a plain dict."""
+    return dict(compiled.cost_analysis())

@@ -31,7 +31,7 @@ import warnings
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 INDEX_AXIS = "index"
 
@@ -67,7 +67,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     devices = jax.devices()
     need = _prod(shape)
     if len(devices) >= need:
-        return jax.make_mesh(shape, axes)
+        return jax.make_mesh(shape, axes,
+                             axis_types=(AxisType.Auto,) * len(axes))
     warnings.warn(
         f"mesh fallback: production topology {shape} needs {need} devices "
         f"but only {len(devices)} are available; degrading to a "
@@ -79,7 +80,8 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 def make_host_mesh():
     """1x1 mesh over the real local device — smoke tests and examples."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_index_mesh(n_devices: int = 0):

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def make_distributed_decode_attn(mesh: Mesh, batch_axes, seq_axes: Tuple[str, ...]):
@@ -74,9 +73,9 @@ def make_distributed_decode_attn(mesh: Mesh, batch_axes, seq_axes: Tuple[str, ..
         out = o / jnp.maximum(l, 1e-30).transpose(0, 2, 1)[..., None]
         return out.astype(q.dtype)
 
-    fn = shard_map(local_attn, mesh=mesh,
-                   in_specs=(q_spec, kv_spec, kv_spec, len_spec),
-                   out_specs=out_spec, check_rep=False)
+    fn = jax.shard_map(local_attn, mesh=mesh,
+                       in_specs=(q_spec, kv_spec, kv_spec, len_spec),
+                       out_specs=out_spec, check_vma=False)
 
     def decode_attn(q, k_cache, v_cache, length):
         return fn(q, k_cache, v_cache, length)

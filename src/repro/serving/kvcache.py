@@ -143,7 +143,7 @@ class PageTable:
                 n_devices=n_dev, n_shards=n_shards, capacity=cap,
                 levels=cfg.levels, foresight=cfg.foresight, seed=cfg.seed,
                 key_span=MAX_SEQS << BLOCK_BITS,
-                node_width=cfg.node_width)
+                node_width=cfg.node_width, mesh=self.mesh)
         else:
             self.index = shd.empty_sharded(
                 n_shards=n_shards, capacity=cap, levels=cfg.levels,

@@ -123,9 +123,10 @@ def test_vmem_budget_fires_on_oversized_tile():
 
 
 def test_vmem_budget_double_buffer_vs_pinned():
-    # 7 MiB tile: x1 (pinned) fits 16 MiB total; x2 (streamed) with two
-    # of them would not — the index_map decides which model applies
-    n = (7 * 1024 * 1024) // 4
+    # a tile within the budget and over a quarter of the total: x1
+    # (pinned) fits the total; x2 (streamed) with two of them would not —
+    # the index_map decides which model applies
+    n = min(VMEM_BUDGET_BYTES, TOTAL_VMEM_BYTES * 7 // 16) // 4
     pinned = _launch([_blk((1, n), lambda i: (0, 0), oshape=(4, n)),
                       _blk((1, n), lambda i: (0, 0), oshape=(4, n),
                            out=True, label="out[0]")])
@@ -191,7 +192,7 @@ def test_capture_spy_records_real_pallas_launch():
     fused = jnp.zeros((4, 64, 2), jnp.int32)
     q = jnp.zeros((ft.QBLK,), jnp.int32)
     with capture_pallas_calls(caps, capture_only=True):
-        ft.foresight_traverse(fused, q)
+        ft.foresight_traverse(fused, q, interpret=True)
     assert len(caps) == 1
     cap = caps[0]
     assert cap.kernel_name == "_foresight_kernel"

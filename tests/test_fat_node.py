@@ -252,10 +252,10 @@ def test_mesh_matches_scalar(D):
     mesh = lmesh.make_index_mesh(D)
     keys, rng = _keys(600, seed=9)
     fat = mi.build_mesh_index(jnp.asarray(keys), jnp.asarray(keys * 3),
-                              n_devices=D, n_shards=4, levels=8,
+                              mesh=mesh, n_devices=D, n_shards=4, levels=8,
                               node_width=8)
     ref = mi.build_mesh_index(jnp.asarray(keys), jnp.asarray(keys * 3),
-                              n_devices=D, n_shards=4, levels=8)
+                              mesh=mesh, n_devices=D, n_shards=4, levels=8)
     assert fat.node_width == 8
     q = jnp.asarray(_probe(keys, rng))
     f1, v1 = mi.search_mesh(fat, q, mesh=mesh)

@@ -30,7 +30,7 @@ def test_foresight_kernel_matches_ref(n, cap, levels, batch):
         rng.choice(keys, batch // 2),
         rng.integers(0, 1 << 22, batch - batch // 2),
     ]).astype(np.int32))
-    node_k, key_k = foresight_traverse(st.fused, q)
+    node_k, key_k = foresight_traverse(st.fused, q, interpret=True)
     node_r, key_r = foresight_search_ref(st.fused, q)
     np.testing.assert_array_equal(np.asarray(node_k), np.asarray(node_r))
     np.testing.assert_array_equal(np.asarray(key_k), np.asarray(key_r))
@@ -41,7 +41,7 @@ def test_base_kernel_matches_ref(n, cap, levels):
     st, keys = _state(n, cap, levels, False, seed=n)
     rng = np.random.default_rng(n + 2)
     q = jnp.asarray(rng.integers(0, 1 << 22, 128).astype(np.int32))
-    node_k, key_k = base_traverse(st.nxt, st.keys, q)
+    node_k, key_k = base_traverse(st.nxt, st.keys, q, interpret=True)
     node_r, key_r = base_search_ref(st.nxt, st.keys, q)
     np.testing.assert_array_equal(np.asarray(node_k), np.asarray(node_r))
     np.testing.assert_array_equal(np.asarray(key_k), np.asarray(key_r))

@@ -34,7 +34,7 @@ def test_validated_kernel_clean_table(n, cap, levels):
     st_, keys, rng = _built(n, cap, levels, seed=n)
     q = jnp.asarray(np.concatenate(
         [rng.choice(keys, 64), rng.integers(0, 1 << 20, 64)]).astype(np.int32))
-    node, ck = validated_traverse(st_.fused, st_.keys, q)
+    node, ck = validated_traverse(st_.fused, st_.keys, q, interpret=True)
     kset = set(keys.tolist())
     expect = np.array([int(x) in kset for x in np.asarray(q)])
     np.testing.assert_array_equal(np.asarray(ck) == np.asarray(q), expect)
@@ -50,7 +50,8 @@ def test_validated_kernel_exact_under_corruption(corrupt, seed):
         mask, rng.integers(-2**31 + 1, 2**31 - 1, fused[..., 1].shape),
         fused[..., 1])
     q = jnp.asarray(rng.integers(0, 1 << 20, 128).astype(np.int32))
-    node, ck = validated_traverse(jnp.asarray(fused), st_.keys, q)
+    node, ck = validated_traverse(jnp.asarray(fused), st_.keys, q,
+                                  interpret=True)
     kset = set(keys.tolist())
     expect = np.array([int(x) in kset for x in np.asarray(q)])
     found = np.asarray(ck) == np.asarray(q)
@@ -69,7 +70,8 @@ def test_validated_kernel_matches_core_reference():
         mask, rng.integers(-2**31 + 1, 2**31 - 1, fused[..., 1].shape),
         fused[..., 1])
     q = jnp.asarray(rng.integers(0, 1 << 20, 256).astype(np.int32))
-    node_k, ck = validated_traverse(jnp.asarray(fused), st_.keys, q)
+    node_k, ck = validated_traverse(jnp.asarray(fused), st_.keys, q,
+                                  interpret=True)
     ref = search_validated(jnp.asarray(fused), st_.keys, st_.vals, q)
     found_k = np.asarray(ck) == np.asarray(q)
     np.testing.assert_array_equal(found_k, np.asarray(ref.found))

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import mesh_index as mi
@@ -56,7 +55,7 @@ def _pair(n=192, n_shards=4, levels=8, seed=0, n_devices=1, span=SPAN):
     # rule the mesh page table applies
     cap = shd.shard_capacity_for(n, n_shards)
     mx = mi.build_mesh_index(jnp.asarray(keys), jnp.asarray(vals),
-                             n_devices=n_devices, n_shards=n_shards,
+                             mesh=_mesh(n_devices), n_devices=n_devices, n_shards=n_shards,
                              capacity=cap, levels=levels, seed=seed)
     ref = shd.build_sharded(jnp.asarray(keys), jnp.asarray(vals),
                             n_shards=n_shards, levels=levels, seed=seed)
@@ -106,8 +105,8 @@ def test_search_equivalence_zipf(d):
 def test_kernel_search_equivalence(d):
     mx, ref, keys, rng = _pair(n_devices=d, seed=5)
     q = jnp.asarray(_probes(keys, rng))
-    r = ml.search_kernel_mesh(mx, q, mesh=_mesh(d), interpret=True)
-    er = kops.search_kernel_sharded(ref, q, interpret=True)
+    r = ml.search_kernel_mesh(mx, q, mesh=_mesh(d))
+    er = kops.search_kernel_sharded(ref, q)
     np.testing.assert_array_equal(np.asarray(r.found), np.asarray(er.found))
     np.testing.assert_array_equal(np.asarray(r.vals), np.asarray(er.vals))
     # unified dispatch front door takes the same path
@@ -231,9 +230,10 @@ def test_exchange_roundtrip_identity_under_jit(d):
             did, (q,), (jnp.int32(0),), d)
         return mi._exchange_back(rq, perm, starts, did_s, d)
 
-    fn = jax.jit(shard_map(body, mesh=mesh,
-                           in_specs=(P(), P(lmesh.INDEX_AXIS)),
-                           out_specs=P(lmesh.INDEX_AXIS), check_rep=False))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh,
+                               in_specs=(P(), P(lmesh.INDEX_AXIS)),
+                               out_specs=P(lmesh.INDEX_AXIS),
+                               check_vma=False))
     out = fn(jnp.asarray(db), jnp.asarray(q_host))
     np.testing.assert_array_equal(np.asarray(out), q_host)
 
@@ -290,7 +290,7 @@ def _replay_mesh(seed, *, d, rounds=3, batch=48, zipf=False):
     # device (a Zipf hot span fits inside a single device slice)
     cap = shd.shard_capacity_for(48 + rounds * batch, 4)
     mx = mi.build_mesh_index(jnp.asarray(keys), jnp.asarray(keys * 3),
-                             n_devices=d, n_shards=4, capacity=cap,
+                             mesh=_mesh(d), n_devices=d, n_shards=4, capacity=cap,
                              levels=8, seed=seed)
     oracle = DictOracle()
     for k in keys:

@@ -387,7 +387,7 @@ def test_cluster_plan_k_clamps_to_rebalanced_shard_count():
         from repro.kernels.foresight_traverse import foresight_traverse_clustered
         foresight_traverse_clustered(merged.shards.fused, plan_old.block_sids,
                                      plan_old.ndist, plan_old.sid_sorted,
-                                     plan_old.q_sorted)
+                                     plan_old.q_sorted, interpret=True)
     # replanning against the merged boundaries is the supported path
     f, v = shd.search_sharded(merged, qp)
     rc = kops.search_kernel_sharded(merged, qp)

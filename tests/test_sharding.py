@@ -6,7 +6,7 @@ import sys
 
 import jax
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.launch.mesh import dp_axes, dp_size, make_host_mesh
 from repro.parallel.sharding import Policy, policy_for
@@ -16,7 +16,8 @@ pytestmark = pytest.mark.filterwarnings("ignore")
 
 def _mesh_16x16_sim():
     """A (2,2) mesh with production axis names for spec logic tests."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def test_param_spec_tp_on_divisible_dims():
@@ -30,7 +31,8 @@ def test_param_spec_tp_on_divisible_dims():
 def test_param_spec_row_parallel_fallback():
     """56 heads % 16 -> TP lands on the contraction dim instead."""
     p = Policy()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     # simulate 16-wide axis via divisibility check: use indivisible dim
     spec = p.param_spec(("embed", "heads", "head_dim"), mesh, (64, 56, 128))
     # heads=56 divisible by 1 in this tiny mesh; force with axsize>1 later
@@ -84,13 +86,15 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 from repro.configs import get_smoke
 from repro.optim import adamw
 from repro.parallel.sharding import Policy
 from repro.train import step as STEP
 
 cfg = get_smoke("llama3_8b")
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 policy = Policy()
 fn, shd, (p_abs, o_abs) = STEP.make_train_step(
     cfg, policy, mesh, 4, adamw.AdamWConfig())
@@ -113,12 +117,14 @@ def test_dryrun_decode_on_8_virtual_devices():
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
+from jax.sharding import AxisType
 from repro.configs import get_smoke
 from repro.parallel.sharding import Policy
 from repro.train import step as STEP
 
 cfg = get_smoke("llama3_8b")
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 policy = Policy()
 fn, shd, (p_abs, cache_abs) = STEP.make_decode_step(cfg, policy, mesh, 4, 64)
 batch = STEP.decode_input_specs(cfg, 4)
