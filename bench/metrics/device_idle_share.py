@@ -1,0 +1,10 @@
+"""``device_idle_share``: percent of the traced window in which no
+operation ran on the device, averaged over the chips used (profiler
+trace)."""
+
+
+def read(run: dict):
+    t = run["trace"]
+    if not t or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
