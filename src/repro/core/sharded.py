@@ -260,6 +260,22 @@ def search_sharded(shl: ShardedSkipList, queries: jax.Array
     same lock-step loop as ``skiplist.search_fast``, generalized by one
     index term.  No host round-trip anywhere.
     """
+    return _search_sharded(shl, queries, count=False)
+
+
+def search_sharded_counted(shl: ShardedSkipList, queries: jax.Array
+                           ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``search_sharded`` that also counts its loop: (found, vals, steps),
+    ``steps`` per lane as ``skiplist.search_fast_counted`` counts them.
+    Each lane starts at its own shard's effective top level."""
+    return _search_sharded(shl, queries, count=True)
+
+
+@jax.named_scope("search_sharded")
+def _search_sharded(shl: ShardedSkipList, queries: jax.Array, *,
+                    count: bool) -> tuple:
+    """The loop of both; with ``count`` it also carries each lane's steps,
+    returned last, as ``skiplist._search_fast`` does."""
     q = queries.astype(jnp.int32)
     B = q.shape[0]
     L, cap = shl.levels, shl.shard_capacity
@@ -283,13 +299,18 @@ def search_sharded(shl: ShardedSkipList, queries: jax.Array
         return jnp.any(carry[1] >= 0)
 
     def body(carry):
-        x, lvl = carry
+        x, lvl = carry[:2]
         active = lvl >= 0
         ptr, fk = gather(jnp.maximum(lvl, 0), x)
         go = active & (fk < q)
-        return jnp.where(go, ptr, x), jnp.where(go | ~active, lvl, lvl - 1)
+        out = (jnp.where(go, ptr, x), jnp.where(go | ~active, lvl, lvl - 1))
+        if count:
+            out += (lax.add(carry[2],
+                            lax.convert_element_type(active, jnp.int32)),)
+        return out
 
-    x, lvl = lax.while_loop(cond, body, (x, lvl))
+    carry = lax.while_loop(cond, body, (x, lvl, x) if count else (x, lvl))
+    x, steps = carry[0], carry[2:]
     cand, ck = gather(jnp.zeros((B,), jnp.int32), x)
     nw = shl.node_width
     if nw > 1:
@@ -306,11 +327,11 @@ def search_sharded(shl: ShardedSkipList, queries: jax.Array
         vals = jnp.where(found,
                          jnp.take(shl.shards.fat_vals.reshape(-1),
                                   base + pos_c), NULL_VAL)
-        return found, vals
+        return (found, vals) + steps
     found = ck == q
     flat_vals = shl.shards.vals.reshape(-1)
     vals = jnp.where(found, jnp.take(flat_vals, sid * cap + cand), NULL_VAL)
-    return found, vals
+    return (found, vals) + steps
 
 
 def contains_sharded(shl: ShardedSkipList, queries: jax.Array) -> jax.Array:
@@ -863,8 +884,9 @@ def apply_ops_sharded(shl: ShardedSkipList, op_types: jax.Array,
         in_place = traced or _has_static_ceiling(shl)
         if in_place:
             from repro.core import rebalance_traced as rbt
-            shl, _ = rbt.exhaustion_guard_traced(
-                shl, op_types, keys, max_shards=max_shards, seed=seed)
+            with jax.named_scope("exhaustion_guard"):
+                shl, _ = rbt.exhaustion_guard_traced(
+                    shl, op_types, keys, max_shards=max_shards, seed=seed)
         else:
             try:
                 shl, _ = _exhaustion_guard(shl, op_types, keys,
@@ -889,14 +911,16 @@ def apply_ops_sharded(shl: ShardedSkipList, op_types: jax.Array,
         # eager default: concretize the widest segment so the pass loop
         # dispatches in ONE window (>= 1: segment lengths sum to B > 0)
         max_segment = int(jnp.max(lens))  # trace-ok: eager branch only (traced callers hit the static-window path)
-    out, results = _apply_segment_passes(shl, op_types, keys, vals,
-                                         perm, starts, lens,
-                                         max_segment=max_segment)
+    with jax.named_scope("segment_passes"):
+        out, results = _apply_segment_passes(shl, op_types, keys, vals,
+                                             perm, starts, lens,
+                                             max_segment=max_segment)
     if rebalance:
         if in_place:
-            out, _ = rbt.watermark_rebalance_traced(
-                out, high_water=high_water, low_water=low_water,
-                max_shards=max_shards, seed=seed)
+            with jax.named_scope("rebalance"):
+                out, _ = rbt.watermark_rebalance_traced(
+                    out, high_water=high_water, low_water=low_water,
+                    max_shards=max_shards, seed=seed)
         else:
             out, _ = _watermark_rebalance(out, high_water=high_water,
                                           low_water=low_water,

@@ -416,8 +416,9 @@ def _search_loop(state: SkipListState, q: jax.Array, stop_level: int):
         gathers = gathers + g * jnp.sum(active).astype(jnp.int32)
         return new_x, jnp.where(active, new_lvl, lvl), preds, steps, gathers
 
-    x, lvl, preds, steps, gathers = lax.while_loop(
-        cond, body, (x, lvl, preds, steps, gathers))
+    with jax.named_scope("search_loop"):
+        x, lvl, preds, steps, gathers = lax.while_loop(
+            cond, body, (x, lvl, preds, steps, gathers))
     return x, preds, steps, gathers
 
 
@@ -513,6 +514,33 @@ def search_fast(state: SkipListState, queries: jax.Array
     wide batches, washing out Foresight's gather saving — and (b) starts at
     the effective top level, skipping ~L - log2(n) empty iterations.
     """
+    return _search_fast(state, queries, count=False)
+
+
+def search_fast_counted(state: SkipListState, queries: jax.Array
+                        ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``search_fast`` that also counts its loop: (found, vals, steps).
+
+    ``steps[b]`` is the number of lock-step iterations in which lane ``b``
+    was still searching, each one dependent gather (two without
+    foresight).  A lane that stops stays stopped, so the loop ran
+    ``max(steps)`` iterations.  ``search`` counts the same gathers (also
+    without the final candidate gather) but starts every lane at level
+    ``L - 1``, one step a level above ``effective_top_level``, so its
+    ``gathers`` exceed ``g * sum(steps)`` by exactly
+    ``B * g * (L - 1 - effective_top_level)``.  The count is a
+    primitive-level add in the loop, so the eager read path lowers no
+    nested program for it.
+    """
+    return _search_fast(state, queries, count=True)
+
+
+@jax.named_scope("search_fast")
+def _search_fast(state: SkipListState, queries: jax.Array, *, count: bool
+                 ) -> tuple:
+    """The loop of both; with ``count`` it also carries each lane's steps,
+    returned last.  Without, the eager read path lowers the smaller
+    program on every call."""
     q = queries.astype(jnp.int32)
     B = q.shape[0]
     x = jnp.zeros((B,), jnp.int32)
@@ -522,7 +550,7 @@ def search_fast(state: SkipListState, queries: jax.Array
         return jnp.any(carry[1] >= 0)
 
     def body(carry):
-        x, lvl = carry
+        x, lvl = carry[:2]
         active = lvl >= 0
         safe_lvl = jnp.maximum(lvl, 0)
         if state.foresight:
@@ -530,9 +558,14 @@ def search_fast(state: SkipListState, queries: jax.Array
         else:
             ptr, fk = _gather_base(state.nxt, state.keys, safe_lvl, x)
         go = active & (fk < q)
-        return jnp.where(go, ptr, x), jnp.where(go | ~active, lvl, lvl - 1)
+        out = (jnp.where(go, ptr, x), jnp.where(go | ~active, lvl, lvl - 1))
+        if count:
+            out += (lax.add(carry[2],
+                            lax.convert_element_type(active, jnp.int32)),)
+        return out
 
-    x, lvl = lax.while_loop(cond, body, (x, lvl))
+    carry = lax.while_loop(cond, body, (x, lvl, x) if count else (x, lvl))
+    x, steps = carry[0], carry[2:]
     if state.foresight:
         cand, ck = _read_fused(state.fused, jnp.zeros((B,), jnp.int32), x)
     else:
@@ -543,10 +576,10 @@ def search_fast(state: SkipListState, queries: jax.Array
         flat = owner * state.node_width + pos_c
         vals = jnp.where(found,
                          jnp.take(state.fat_vals.reshape(-1), flat), NULL_VAL)
-        return found, vals
+        return (found, vals) + steps
     found = ck == q
     vals = jnp.where(found, jnp.take(state.vals, cand), NULL_VAL)
-    return found, vals
+    return (found, vals) + steps
 
 
 def _scatter_rows(preds: jax.Array, lvl: jax.Array, x: jax.Array,
@@ -952,10 +985,12 @@ def apply_ops(state: SkipListState, op_types: jax.Array, keys: jax.Array,
             r = search(s, k[None])
             return s, r.found[0].astype(jnp.int32)
         def do_ins(s):
-            s2, okk = insert(s, k, v)
+            with jax.named_scope("insert"):
+                s2, okk = insert(s, k, v)
             return s2, okk.astype(jnp.int32)
         def do_del(s):
-            s2, okk = delete(s, k)
+            with jax.named_scope("delete"):
+                s2, okk = delete(s, k)
             return s2, okk.astype(jnp.int32)
         return lax.switch(t, [do_read, do_ins, do_del], st)
 
@@ -1061,6 +1096,7 @@ def to_sorted_keys(state: SkipListState, max_n: int) -> jax.Array:
 # Range queries — the skiplist's signature advantage over hash indexes
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("range_scan")
 def range_scan(state: SkipListState, lo: jax.Array, hi: jax.Array,
                max_out: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Collect up to ``max_out`` (key, val) pairs with lo <= key < hi.

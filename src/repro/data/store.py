@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import sharded as shd
 from repro.core import skiplist as sl
 from repro.kernels import ops as kops
@@ -117,20 +118,36 @@ class IndexedSampleStore:
     # -- lookups ------------------------------------------------------------
 
     def lookup(self, keys: jax.Array) -> Tuple[jax.Array, jax.Array]:
-        """Batched key lookup -> (found [B], row_ids [B])."""
+        """Batched key lookup -> (found [B], row_ids [B]).
+
+        Under a profiler trace the XLA traversals add their loop counts to
+        ``obs``'s counters."""
+        n = keys.shape[0]
         if self.cfg.use_kernel:
             r = kops.search_kernel(self.index, keys,   # auto-dispatches
                                    cluster=self.cfg.clustered)
             return r.found, r.vals
         if self.sharded:
-            return shd.search_sharded(self.index, keys)
-        return sl.search_fast(self.index, keys)   # preds-free read path
+            name, plain, counted = ("read.search_sharded", shd.search_sharded,
+                                    shd.search_sharded_counted)
+        else:   # preds-free read path
+            name, plain, counted = ("read.search_fast", sl.search_fast,
+                                    sl.search_fast_counted)
+        with obs.span(name, ops=n):
+            if not obs.counting():
+                return plain(self.index, keys)
+            found, vals, steps = counted(self.index, keys)
+        obs.count_search(steps, per_step=1 if self.cfg.foresight else 2)
+        return found, vals
 
     def get_batch(self, keys: jax.Array) -> Tuple[jax.Array, jax.Array]:
         """Fetch token rows for keys (missing keys fall back to row 0)."""
-        found, row_ids = self.lookup(keys)
-        safe = jnp.where(found, row_ids, 0)
-        return self.rows[safe], found
+        n = keys.shape[0]
+        with obs.span("store.get_batch", ops=n):
+            found, row_ids = self.lookup(keys)
+            with obs.span("store.gather_rows", ops=n):
+                safe = jnp.where(found, row_ids, 0)
+                return self.rows[safe], found
 
     def range_scan(self, lo, hi, max_out: int
                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -145,12 +162,14 @@ class IndexedSampleStore:
 
     def _apply(self, ops: jax.Array, keys: jax.Array, vals: jax.Array
                ) -> jax.Array:
+        n = ops.shape[0]
         if self.sharded:
-            self.index, results = shd.apply_ops_sharded(
-                self.index, ops, keys, vals,
-                rebalance=self.cfg.rebalance,
-                max_shards=self.cfg.max_shards or shd.MAX_SHARDS,
-                seed=self.cfg.seed)
+            with obs.span("write.apply_ops_sharded", ops=n):
+                self.index, results = shd.apply_ops_sharded(
+                    self.index, ops, keys, vals,
+                    rebalance=self.cfg.rebalance,
+                    max_shards=self.cfg.max_shards or shd.MAX_SHARDS,
+                    seed=self.cfg.seed)
             self._updates_since_repack += 1
             if (self.cfg.rebalance and self.cfg.repack_every and
                     self._updates_since_repack >= self.cfg.repack_every):
@@ -159,17 +178,21 @@ class IndexedSampleStore:
         else:
             # donated: the old table's buffers become the new one's, so a
             # table near the device's size is never held twice
-            self.index, results = _apply_donated(self.index, ops, keys, vals)
+            with obs.span("write.apply_ops", ops=n):
+                self.index, results = _apply_donated(self.index, ops, keys,
+                                                     vals)
         return results
 
     def ingest(self, keys: jax.Array, row_ids: jax.Array) -> jax.Array:
         """Insert new key->row mappings (linearized batch)."""
-        ops = jnp.full(keys.shape, sl.OP_INSERT, jnp.int32)
-        return self._apply(ops, keys, row_ids)
+        with obs.span("store.ingest", ops=keys.shape[0]):
+            ops = jnp.full(keys.shape, sl.OP_INSERT, jnp.int32)
+            return self._apply(ops, keys, row_ids)
 
     def evict(self, keys: jax.Array) -> jax.Array:
-        ops = jnp.full(keys.shape, sl.OP_DELETE, jnp.int32)
-        return self._apply(ops, keys, jnp.zeros_like(keys))
+        with obs.span("store.evict", ops=keys.shape[0]):
+            ops = jnp.full(keys.shape, sl.OP_DELETE, jnp.int32)
+            return self._apply(ops, keys, jnp.zeros_like(keys))
 
 
 def _markov_corpus(rng: np.random.Generator, n: int, width: int,

@@ -53,13 +53,13 @@ forced capacity failure).
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import mesh_index as mshi
 from repro.core import sharded as shd
 from repro.core import skiplist as sl
@@ -156,34 +156,49 @@ class PageTable:
         # self.index with the result, so the old buffers (a full table at
         # the ceiling) can be reused instead of held alive alongside it.
         # (The mesh path jits inside apply_ops_mesh, cached per mesh.)
+        # A named function, so that its program and device ops are named
+        # ``jit_apply_ops_sharded`` in traces and compile logs.
+        def apply_ops_sharded(index, ops, keys, vals):
+            return shd.apply_ops_sharded(index, ops, keys, vals,
+                                         rebalance=cfg.rebalance,
+                                         seed=cfg.seed)
         self._jit_apply = None if self.mesh is not None else jax.jit(
-            functools.partial(shd.apply_ops_sharded, rebalance=cfg.rebalance,
-                              seed=cfg.seed),
-            donate_argnums=(0,))
+            apply_ops_sharded, donate_argnums=(0,))
 
     def _apply(self, ops: jax.Array, keys: jax.Array, vals: jax.Array
                ) -> jax.Array:
         n = ops.shape[0]
         pad = (1 if n == 0 else 1 << int(n - 1).bit_length()) - n
-        if pad:  # no-op reads of key 0: no state, RNG, or routing effect
-            ops = jnp.concatenate([ops, jnp.full((pad,), sl.OP_READ,
-                                                 jnp.int32)])
-            keys = jnp.concatenate([keys, jnp.zeros((pad,), jnp.int32)])
-            vals = jnp.concatenate([vals, jnp.zeros((pad,), jnp.int32)])
+        with obs.span("page_table.pad", ops=n, padded=pad):
+            if pad:  # no-op reads of key 0: no state, RNG, or routing effect
+                ops = jnp.concatenate([ops, jnp.full((pad,), sl.OP_READ,
+                                                     jnp.int32)])
+                keys = jnp.concatenate([keys, jnp.zeros((pad,), jnp.int32)])
+                vals = jnp.concatenate([vals, jnp.zeros((pad,), jnp.int32)])
         if self.mesh is not None:
             self.index, results, self.load_stats = mshi.apply_ops_mesh(
                 self.index, ops, keys, vals, mesh=self.mesh,
                 rebalance=self.cfg.rebalance, seed=self.cfg.seed)
         else:
-            self.index, results = self._jit_apply(self.index, ops, keys,
-                                                  vals)
+            with obs.span("write.apply_ops_sharded", ops=n):
+                self.index, results = self._jit_apply(self.index, ops, keys,
+                                                      vals)
         return results[:n]
 
     def _search(self, keys: jax.Array) -> Tuple[jax.Array, jax.Array]:
-        """Traversal-loop lookup on whichever table variant is live."""
+        """Traversal-loop lookup on whichever table variant is live; under
+        a profiler trace the single-device loop adds its counts to
+        ``obs``'s counters."""
         if self.mesh is not None:
             return mshi.search_mesh(self.index, keys, mesh=self.mesh)
-        return shd.search_sharded(self.index, keys)
+        n = keys.shape[0]
+        with obs.span("read.search_sharded", ops=n):
+            if not obs.counting():
+                return shd.search_sharded(self.index, keys)
+            found, vals, steps = shd.search_sharded_counted(
+                self.index, keys)
+        obs.count_search(steps, per_step=1 if self.cfg.foresight else 2)
+        return found, vals
 
     def _validate_ids(self, seq_ids, block_ids) -> None:
         seq = np.atleast_1d(np.asarray(seq_ids, np.int64))
@@ -213,8 +228,9 @@ class PageTable:
         """
         n = len(keys)
         ops = jnp.full((n,), sl.OP_INSERT, jnp.int32)
-        res = np.asarray(self._apply(ops, jnp.asarray(keys),  # trace-ok: single batched sync; result gates host-side reclaim
-                                     jnp.asarray(pages)))
+        res = self._apply(ops, jnp.asarray(keys), jnp.asarray(pages))
+        with obs.span("page_table.host_sync", ops=n):
+            res = np.asarray(res)  # trace-ok: single batched sync; result gates host-side reclaim
         lost = np.zeros(n, bool)
         if not res.all():
             failed = res == 0
@@ -234,14 +250,17 @@ class PageTable:
         (lost pages reclaimed first) — exhaustion is a caller bug here.
         The serving plane uses ``try_alloc`` instead and degrades.
         """
-        self._validate_ids(seq_ids, block_ids)
         n = len(seq_ids)
-        if n > len(self.free):
-            raise RuntimeError("KV page pool exhausted")
-        pages = np.array([self.free.pop() for _ in range(n)], np.int32)
-        keys = page_key(seq_ids.astype(np.int64),
-                        block_ids.astype(np.int64)).astype(np.int32)
-        lost = self._insert_pages(keys, pages)
+        with obs.span("page_table.alloc", ops=n):
+            self._validate_ids(seq_ids, block_ids)
+            if n > len(self.free):
+                raise RuntimeError("KV page pool exhausted")
+            with obs.span("page_table.free_list", ops=n):
+                pages = np.array([self.free.pop() for _ in range(n)],
+                                 np.int32)
+            keys = page_key(seq_ids.astype(np.int64),
+                            block_ids.astype(np.int64)).astype(np.int32)
+            lost = self._insert_pages(keys, pages)
         if lost.any():
             raise RuntimeError(
                 f"page-table insert failed for {int(lost.sum())} block(s): "
@@ -297,14 +316,17 @@ class PageTable:
         convert once per batch at their own boundary (as ``release``
         does), never per element.
         """
-        self._validate_ids(seq_ids, block_ids)
-        keys = jnp.asarray(page_key(seq_ids.astype(np.int64),
-                                    block_ids.astype(np.int64))
-                           .astype(np.int32))
-        if self.cfg.use_kernel:
-            r = kops.search_kernel(self.index, keys, mesh=self.mesh)
-            return r.found, r.vals
-        return self._search(keys)
+        n = len(seq_ids)
+        with obs.span("page_table.lookup", ops=n):
+            with obs.span("page_table.validate", ops=n):
+                self._validate_ids(seq_ids, block_ids)
+                keys = jnp.asarray(page_key(seq_ids.astype(np.int64),
+                                            block_ids.astype(np.int64))
+                                   .astype(np.int32))
+            if self.cfg.use_kernel:
+                r = kops.search_kernel(self.index, keys, mesh=self.mesh)
+                return r.found, r.vals
+            return self._search(keys)
 
     def release(self, seq_id: int, n_blocks: int) -> int:
         """Free all pages of a finished sequence (ordered range delete)."""
@@ -322,19 +344,23 @@ class PageTable:
         n_blocks = blocks.size
         if n_blocks == 0:
             return 0
-        self._validate_ids(seq_id, blocks)
-        keys = page_key(np.int64(seq_id), blocks).astype(np.int32)
-        found, pages = self.lookup(np.full(n_blocks, seq_id), blocks)
-        ops = jnp.full((n_blocks,), sl.OP_DELETE, jnp.int32)
-        self._apply(ops, jnp.asarray(keys), jnp.zeros(n_blocks, jnp.int32))
-        # ONE batched device->host sync at the eager API boundary (the free
-        # list is host state); the old per-element loop synced implicitly
-        # through python iteration over device arrays
-        fnp = np.asarray(found, bool)      # trace-ok: single batched sync at eager API boundary
-        pnp = np.asarray(pages)            # trace-ok: single batched sync at eager API boundary
-        live = pnp[fnp]
-        self.free.extend(int(p) for p in live.tolist())
-        return int(fnp.sum())
+        with obs.span("page_table.release", ops=n_blocks):
+            self._validate_ids(seq_id, blocks)
+            keys = page_key(np.int64(seq_id), blocks).astype(np.int32)
+            found, pages = self.lookup(np.full(n_blocks, seq_id), blocks)
+            ops = jnp.full((n_blocks,), sl.OP_DELETE, jnp.int32)
+            self._apply(ops, jnp.asarray(keys),
+                        jnp.zeros(n_blocks, jnp.int32))
+            # ONE batched device->host sync at the eager API boundary (the
+            # free list is host state); the old per-element loop synced
+            # implicitly through python iteration over device arrays
+            with obs.span("page_table.host_sync", ops=n_blocks):
+                fnp = np.asarray(found, bool)  # trace-ok: single batched sync at eager API boundary
+                pnp = np.asarray(pages)        # trace-ok: single batched sync at eager API boundary
+            with obs.span("page_table.free_list", ops=n_blocks):
+                live = pnp[fnp]
+                self.free.extend(int(p) for p in live.tolist())
+            return int(fnp.sum())
 
     # -- pool pressure ---------------------------------------------------------
 
