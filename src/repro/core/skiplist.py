@@ -335,7 +335,16 @@ def _build_fat(keys: jax.Array, vals: jax.Array, *, capacity: int,
 # ---------------------------------------------------------------------------
 
 def _gather_fused(fused: jax.Array, lvl: jax.Array, x: jax.Array):
-    """ONE gather: fetch (next_ptr, next_key) for nodes ``x`` at levels ``lvl``."""
+    """ONE gather: fetch (next_ptr, next_key) for nodes ``x`` at levels ``lvl``.
+
+    Through a flat ``[L * cap, 2]`` view of the table.  The fat layout's
+    search and updates, and the level-0 walks of ``range_scan`` and
+    ``to_sorted_keys``, read the table this way.  The scalar search and
+    write path read the table's two planes instead (``_split``): on TPU the
+    table lies as those planes, pair dimension outermost, so the flat view
+    is a relayout copy of the whole table, which the compiler sinks into
+    the body of a loop that reads it, one copy per search step.
+    """
     cap = fused.shape[1]
     flat = fused.reshape((-1, 2))
     rec = jnp.take(flat, lvl * cap + x, axis=0)           # [B, 2]
@@ -345,10 +354,11 @@ def _gather_fused(fused: jax.Array, lvl: jax.Array, x: jax.Array):
 def _read_fused(fused: jax.Array, lvl: jax.Array, x: jax.Array):
     """``_gather_fused`` for read-only programs: indexes the table in place.
 
-    The write path keeps ``_gather_fused``: on TPU, a loop that reads the
-    table this way followed by an in-place write to it makes the compiler
-    copy the whole table into a layout 64 times its size, where the flat
-    view costs one copy of its own size.
+    ``search_fast`` reads the table this way.  Not for a program that also
+    writes the table: on TPU, a loop that reads the table this way followed
+    by an in-place write to it makes the compiler copy the whole table into
+    a layout 64 times its size.  The scalar write path reads the table's
+    planes (``_split``); the fat layout's keeps ``_gather_fused``.
     """
     rec = fused[lvl, x]                                   # [B, 2]
     return rec[..., 0], rec[..., 1]
@@ -360,6 +370,59 @@ def _gather_base(nxt: jax.Array, keys: jax.Array, lvl: jax.Array, x: jax.Array):
     ptr = jnp.take(nxt.reshape(-1), lvl * cap + x, axis=0)  # gather 1
     fk = jnp.take(keys, ptr, axis=0)                        # gather 2 (dependent)
     return ptr, fk
+
+
+def _table_gather(state: SkipListState):
+    """(gather(lvl, x) -> (next_ptr, next_key), dependent gathers a step)
+    over the state's own table."""
+    if state.foresight:
+        return functools.partial(_gather_fused, state.fused), 1
+    return functools.partial(_gather_base, state.nxt, state.keys), 2
+
+
+# ---------------------------------------------------------------------------
+# The scalar table as planes
+# ---------------------------------------------------------------------------
+#
+# The scalar search and write path hold the table as planes of [L, cap]:
+# the fused table's pointers and next keys, ``(fused[..., 0],
+# fused[..., 1])``, or the base table ``(nxt,)``.  A search indexes each
+# plane in place and a splice writes them with element scatters.  On TPU a
+# loop that reads and writes the fused ``[L, cap, 2]`` table holds it as
+# two such planes anyway (pair dimension outermost), and reading it through
+# a flat view made the compiler copy the whole table once per search step.
+# ``apply_ops`` splits once before its scan and stacks once after it.
+
+def _split(state: SkipListState) -> Tuple[SkipListState, tuple]:
+    """(the state without its table, the table's planes)."""
+    if state.foresight:
+        return (state._replace(fused=None),
+                (state.fused[..., 0], state.fused[..., 1]))
+    return state._replace(nxt=None), (state.nxt,)
+
+
+def _stack(rest: SkipListState, planes: tuple) -> SkipListState:
+    """Inverse of ``_split``.  Stacked on a leading axis and moved last, the
+    planes reach the TPU's tiling of the fused table with fewer temporaries
+    than a stack on the last axis (5.50 against 7.38 GB at capacity 2^24,
+    the same 2.42 GB at 2^23)."""
+    if len(planes) == 2:
+        return rest._replace(fused=jnp.moveaxis(jnp.stack(planes), 0, -1))
+    return rest._replace(nxt=planes[0])
+
+
+def _plane_gather(planes: tuple, keys: jax.Array):
+    """``_table_gather`` over planes, each indexed in place: one gather a
+    step with foresight; base keeps its two dependent gathers."""
+    if len(planes) == 2:
+        ptr, nkey = planes
+        return (lambda lvl, x: (ptr[lvl, x], nkey[lvl, x])), 1
+    nxt = planes[0]
+
+    def gather(lvl, x):
+        ptr = nxt[lvl, x]
+        return ptr, jnp.take(keys, ptr, axis=0)
+    return gather, 2
 
 
 # ---------------------------------------------------------------------------
@@ -375,16 +438,18 @@ class SearchResult(NamedTuple):
     gathers: jax.Array   # [] int32 — dependent-gather count (arch. counter)
 
 
-def _search_loop(state: SkipListState, q: jax.Array, stop_level: int):
+def _search_loop(gather, per_step: int, L: int, q: jax.Array,
+                 stop_level: int):
     """The level-synchronous traversal loop: (x, preds, steps, gathers).
 
+    ``gather(lvl, x)`` fetches ``(next_ptr, next_key)`` with ``per_step``
+    dependent gathers (``_table_gather``, ``_plane_gather``).
     Layout-agnostic — under the fat layout ``keys``/``fused`` are node-level
     (run minima), so ``x`` lands on the level-``stop_level`` predecessor
     NODE and each counted gather is a tile gather servicing ``node_width``
     comparisons.
     """
     B = q.shape[0]
-    L = state.levels
     x = jnp.zeros((B,), jnp.int32)                # start at head
     lvl = jnp.full((B,), L - 1, jnp.int32)
     preds = jnp.zeros((B, L), jnp.int32)
@@ -399,12 +464,7 @@ def _search_loop(state: SkipListState, q: jax.Array, stop_level: int):
         x, lvl, preds, steps, gathers = carry
         active = lvl >= stop_level
         safe_lvl = jnp.maximum(lvl, 0)
-        if state.foresight:
-            ptr, fk = _gather_fused(state.fused, safe_lvl, x)
-            g = jnp.int32(1)
-        else:
-            ptr, fk = _gather_base(state.nxt, state.keys, safe_lvl, x)
-            g = jnp.int32(2)
+        ptr, fk = gather(safe_lvl, x)
         go_right = active & (fk < q)
         new_x = jnp.where(go_right, ptr, x)
         # On descend, record predecessor for the level we are leaving.
@@ -413,7 +473,7 @@ def _search_loop(state: SkipListState, q: jax.Array, stop_level: int):
         new_lvl = jnp.where(go_right, lvl, lvl - 1)
         new_lvl = jnp.where(active, new_lvl, lvl)
         steps = steps + 1
-        gathers = gathers + g * jnp.sum(active).astype(jnp.int32)
+        gathers = gathers + per_step * jnp.sum(active).astype(jnp.int32)
         return new_x, jnp.where(active, new_lvl, lvl), preds, steps, gathers
 
     with jax.named_scope("search_loop"):
@@ -460,26 +520,34 @@ def search(state: SkipListState, queries: jax.Array,
     of the final candidate gather (fig8 comparability across layouts).
     """
     q = queries.astype(jnp.int32)
+    if state.node_width == 1:
+        return _locate(_split(state)[1], state.keys, state.vals, q,
+                       stop_level)
     B = q.shape[0]
-    x, preds, steps, gathers = _search_loop(state, q, stop_level)
-
+    gather, per_step = _table_gather(state)
+    x, preds, steps, gathers = _search_loop(gather, per_step, state.levels,
+                                            q, stop_level)
     # The candidate is the successor of the level-``stop_level`` predecessor.
-    if state.foresight:
-        cand, cand_key = _gather_fused(
-            state.fused, jnp.full((B,), stop_level, jnp.int32), x)
-    else:
-        cand, cand_key = _gather_base(
-            state.nxt, state.keys, jnp.full((B,), stop_level, jnp.int32), x)
-    if state.node_width > 1:
-        owner, pos, pos_c, found = _fat_resolve_batch(state, q, x, cand,
-                                                      cand_key)
-        flat = owner * state.node_width + pos_c
-        vals = jnp.where(found,
-                         jnp.take(state.fat_vals.reshape(-1), flat), NULL_VAL)
-        node = jnp.where(found, flat, TAIL)
-        return SearchResult(found, vals, node, preds, steps, gathers)
+    cand, cand_key = gather(jnp.full((B,), stop_level, jnp.int32), x)
+    owner, pos, pos_c, found = _fat_resolve_batch(state, q, x, cand, cand_key)
+    flat = owner * state.node_width + pos_c
+    vals = jnp.where(found,
+                     jnp.take(state.fat_vals.reshape(-1), flat), NULL_VAL)
+    node = jnp.where(found, flat, TAIL)
+    return SearchResult(found, vals, node, preds, steps, gathers)
+
+
+def _locate(planes: tuple, keys: jax.Array, vals: jax.Array, q: jax.Array,
+            stop_level: int = 0) -> SearchResult:
+    """``search`` of the scalar layout, on the table's planes."""
+    B = q.shape[0]
+    gather, per_step = _plane_gather(planes, keys)
+    x, preds, steps, gathers = _search_loop(gather, per_step,
+                                            planes[0].shape[0], q, stop_level)
+    # The candidate is the successor of the level-``stop_level`` predecessor.
+    cand, cand_key = gather(jnp.full((B,), stop_level, jnp.int32), x)
     found = cand_key == q
-    vals = jnp.where(found, jnp.take(state.vals, cand), NULL_VAL)
+    vals = jnp.where(found, jnp.take(vals, cand), NULL_VAL)
     node = jnp.where(found, cand, TAIL)
     return SearchResult(found, vals, node, preds, steps, gathers)
 
@@ -616,6 +684,7 @@ def insert(state: SkipListState, key: jax.Array, val: jax.Array
     successor at level ``l`` changes to the new node, we write the pair
     ``(new_id, key)`` into ``p``'s fused record *together* (the SIMD-store
     analogue), and the new node's fused record inherits ``p``'s old pair.
+    The splice is ``_apply_one``'s, the one ``apply_ops`` scans.
 
     Fat layout dispatches to ``_fat_insert`` (lane-shift into the owner run,
     median split when full) — same signalled-failure contract on node-slot
@@ -623,58 +692,7 @@ def insert(state: SkipListState, key: jax.Array, val: jax.Array
     """
     if state.node_width > 1:
         return _fat_insert(state, key, val)
-    key = key.astype(jnp.int32)
-    res = search(state, key[None])
-    found = res.found[0]
-    preds = res.preds[0]                                  # [L]
-    L = state.levels
-
-    # Upsert path: key already present -> overwrite value.
-    upsert_vals = state.vals.at[res.node[0]].set(
-        jnp.where(found, val.astype(jnp.int32), state.vals[res.node[0]]))
-
-    st, nid, ok = _alloc(state)
-    rng, sub = jax.random.split(st.rng)
-    h = sample_heights(sub, (), st.levels)
-    do = ok & ~found
-
-    lvls = jnp.arange(L, dtype=jnp.int32)
-    link = do & (lvls < h)                                # [L] levels to splice
-
-    if state.foresight:
-        fused = st.fused
-        old = fused[lvls, preds, :]                       # [L, 2] preds' pairs
-        # New node's pair per level = predecessor's old pair (succ ptr + key).
-        new_pair = jnp.where(link[:, None], old,
-                             fused[lvls, jnp.full((L,), nid), :])
-        fused = fused.at[lvls, jnp.full((L,), nid, jnp.int32), :].set(new_pair)
-        # Predecessors' pair = (new node, key) — written together.
-        pred_pair = jnp.stack(
-            [jnp.where(link, nid, old[:, 0]),
-             jnp.where(link, key, old[:, 1])], axis=-1)
-        fused = fused.at[lvls, preds, :].set(pred_pair)
-        nxt = None
-    else:
-        nxt = st.nxt
-        old_ptr = nxt[lvls, preds]
-        new_ptr = jnp.where(link, old_ptr, nxt[lvls, jnp.full((L,), nid)])
-        nxt = nxt.at[lvls, jnp.full((L,), nid, jnp.int32)].set(new_ptr)
-        nxt = nxt.at[lvls, preds].set(jnp.where(link, nid, old_ptr))
-        fused = None
-
-    keys = st.keys.at[nid].set(jnp.where(do, key, st.keys[nid]))
-    vals = upsert_vals.at[nid].set(jnp.where(do, val.astype(jnp.int32),
-                                             upsert_vals[nid]))
-    height = st.height.at[nid].set(jnp.where(do, h, st.height[nid]))
-    n = st.n + jnp.where(do, 1, 0).astype(jnp.int32)
-
-    # If we did not insert, roll back the allocation.
-    st2 = st._replace(keys=keys, vals=vals, height=height, nxt=nxt,
-                      fused=fused, n=n, rng=rng)
-    st2 = lax.cond(do, lambda s: s,
-                   lambda s: s._replace(free_top=state.free_top,
-                                        bump=state.bump), st2)
-    return st2, do
+    return _apply_single(state, OP_INSERT, key, val)
 
 
 def delete(state: SkipListState, key: jax.Array
@@ -684,45 +702,87 @@ def delete(state: SkipListState, key: jax.Array
     Splice-out rewrites each predecessor's fused pair to the deleted node's
     pair at that level (again pair-at-once).  The slot is pushed on the
     freelist; its key/height stay intact until reuse — the versioned-world
-    analogue of epoch-based reclamation (see DESIGN.md §8).
+    analogue of epoch-based reclamation (see DESIGN.md §8).  The splice is
+    ``_apply_one``'s, the one ``apply_ops`` scans.
 
     Fat layout dispatches to ``_fat_delete`` (lane-shift out of the owner
     run; an emptied node splices out and returns to the freelist).
     """
     if state.node_width > 1:
         return _fat_delete(state, key)
-    key = key.astype(jnp.int32)
-    res = search(state, key[None])
-    found = res.found[0]
-    d = res.node[0]
-    preds = res.preds[0]
-    L = state.levels
+    return _apply_single(state, OP_DELETE, key, jnp.int32(0))
+
+
+def _apply_single(state: SkipListState, op: int, key: jax.Array,
+                  val: jax.Array) -> Tuple[SkipListState, jax.Array]:
+    """``insert`` / ``delete`` of the scalar layout: one ``_apply_one``."""
+    rest, planes = _split(state)
+    rest, planes, ok = _apply_one(rest, planes, jnp.int32(op),
+                                  jnp.asarray(key, jnp.int32),
+                                  jnp.asarray(val, jnp.int32))
+    return _stack(rest, planes), ok.astype(jnp.bool_)
+
+
+def _apply_one(rest: SkipListState, planes: tuple, t: jax.Array,
+               key: jax.Array, val: jax.Array
+               ) -> Tuple[SkipListState, tuple, jax.Array]:
+    """One linearized op of any type on the scalar table's planes.
+
+    Returns (rest, planes, result): found / inserted / deleted as int32.
+    One search with predecessors serves all three types and the splice is
+    branch-free, so no ``lax.switch`` or ``lax.cond`` passes the table
+    through.  An insert links a new node after its predecessors, which take
+    ``(new_id, key)``, the new node inheriting their old records; a delete
+    gives its predecessors the deleted node's records.  Each plane takes
+    element scatters at the same levels in the same step, so a record's
+    pointer and next key change together (the paper's pair-at-once store).
+    A read, a failed insert (allocation exhausted) and a delete of an
+    absent key write back what they read.  An insert advances the RNG
+    whether it links or not; reads and deletes leave it.
+    """
+    L = planes[0].shape[0]
+    is_ins = t == OP_INSERT
+    res = _locate(planes, rest.keys, rest.vals, key[None])
+    found, node, preds = res.found[0], res.node[0], res.preds[0]
+    st, nid, ok = _alloc(rest)
+    rng, sub = jax.random.split(rest.rng)
+    h = sample_heights(sub, (), L)
+    do = is_ins & ok & ~found                    # a new node ``nid`` goes in
+    gone = (t == OP_DELETE) & found              # node ``node`` comes out
     lvls = jnp.arange(L, dtype=jnp.int32)
-    h = state.height[d]
-    link = found & (lvls < h)
+    link = do & (lvls < h)
+    unlink = gone & (lvls < rest.height[node])
+    nid_l = jnp.full((L,), nid, jnp.int32)
+    node_l = jnp.full((L,), node, jnp.int32)
+    out = []
+    for tab, linked in zip(planes, (nid, key)):
+        old = tab[lvls, preds]
+        removed = tab[lvls, node_l]
+        tab = tab.at[lvls, nid_l].set(jnp.where(link, old, tab[lvls, nid_l]))
+        out.append(tab.at[lvls, preds].set(
+            jnp.where(link, linked, jnp.where(unlink, removed, old))))
 
-    if state.foresight:
-        fused = state.fused
-        d_pair = fused[lvls, jnp.full((L,), d), :]        # node d's own pairs
-        old = fused[lvls, preds, :]
-        pred_pair = jnp.where(link[:, None], d_pair, old)
-        fused = fused.at[lvls, preds, :].set(pred_pair)
-        nxt = None
-    else:
-        nxt = state.nxt
-        d_ptr = nxt[lvls, jnp.full((L,), d)]
-        old = nxt[lvls, preds]
-        nxt = nxt.at[lvls, preds].set(jnp.where(link, d_ptr, old))
-        fused = None
-
-    free_list = state.free_list.at[state.free_top].set(
-        jnp.where(found, d, state.free_list[state.free_top]))
-    free_top = state.free_top + jnp.where(found, 1, 0).astype(jnp.int32)
-    keys = state.keys.at[d].set(jnp.where(found, KEY_MAX, state.keys[d]))
-    height = state.height.at[d].set(jnp.where(found, 0, state.height[d]))
-    n = state.n - jnp.where(found, 1, 0).astype(jnp.int32)
-    return state._replace(keys=keys, height=height, nxt=nxt, fused=fused,
-                          n=n, free_list=free_list, free_top=free_top), found
+    # The one slot whose key, value and height change: the new node, or
+    # the found one (an upsert's value, a delete's key and height).
+    slot = jnp.where(do, nid, node)
+    keys = rest.keys.at[slot].set(
+        jnp.where(do, key, jnp.where(gone, KEY_MAX, rest.keys[slot])))
+    vals = rest.vals.at[slot].set(
+        jnp.where(is_ins & (do | found), val, rest.vals[slot]))
+    height = rest.height.at[slot].set(
+        jnp.where(do, h, jnp.where(gone, 0, rest.height[slot])))
+    free_list = rest.free_list.at[rest.free_top].set(
+        jnp.where(gone, node, rest.free_list[rest.free_top]))
+    one = jnp.int32(1)
+    rest = rest._replace(
+        keys=keys, vals=vals, height=height, free_list=free_list,
+        # an allocation that did not link rolls back
+        free_top=jnp.where(do, st.free_top,
+                           rest.free_top + jnp.where(gone, one, 0)),
+        bump=jnp.where(do, st.bump, rest.bump),
+        n=rest.n + jnp.where(do, one, 0) - jnp.where(gone, one, 0),
+        rng=jnp.where(is_ins, rng, rest.rng))
+    return rest, tuple(out), jnp.where(is_ins, do, found).astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -731,12 +791,9 @@ def delete(state: SkipListState, key: jax.Array
 
 def _fat_locate(state: SkipListState, key: jax.Array):
     """(owner, pos, present, preds, x) for one fat-layout key."""
-    x, preds, _, _ = _search_loop(state, key[None], 0)
-    if state.foresight:
-        cand, ck = _gather_fused(state.fused, jnp.zeros((1,), jnp.int32), x)
-    else:
-        cand, ck = _gather_base(state.nxt, state.keys,
-                                jnp.zeros((1,), jnp.int32), x)
+    gather, per_step = _table_gather(state)
+    x, preds, _, _ = _search_loop(gather, per_step, state.levels, key[None], 0)
+    cand, ck = gather(jnp.zeros((1,), jnp.int32), x)
     owner, pos, _, present = _fat_resolve_batch(state, key[None], x, cand, ck)
     return owner[0], pos[0], present[0], preds[0], x[0]
 
@@ -846,7 +903,8 @@ def _fat_insert(state: SkipListState, key: jax.Array, val: jax.Array
         # Splice preds for the median — strictly inside the owner's run, so
         # the level-0 predecessor is the owner itself; the new node lands
         # AFTER it, which keeps ``preds`` (head chain) valid for at_front.
-        _x2, preds2, _s2, _g2 = _search_loop(st, new_min[None], 0)
+        _x2, preds2, _s2, _g2 = _search_loop(*_table_gather(st), st.levels,
+                                             new_min[None], 0)
         st2 = _splice_node(st2, nid, new_min, h, preds2[0], ok)
         hi_k = jnp.where(lane < Bw - half,
                          run_k[jnp.minimum(lane + half, Bw - 1)], KEY_MAX)
@@ -977,7 +1035,20 @@ def apply_ops(state: SkipListState, op_types: jax.Array, keys: jax.Array,
     (found / inserted / deleted as int32 0/1).  This is the functional
     analogue of a concurrent update window: the batch linearizes exactly like
     the paper's concurrent operations do.
+
+    The scalar layout scans ``_apply_one`` over the table's planes, split
+    once before the scan and stacked once after it, so no op copies the
+    table.  The fat layout switches over ``insert`` / ``delete``.
     """
+    ops = (op_types.astype(jnp.int32), keys.astype(jnp.int32),
+           vals.astype(jnp.int32))
+    if state.node_width == 1:
+        def scalar_step(carry, op):
+            rest, planes, r = _apply_one(*carry, *op)
+            return (rest, planes), r
+
+        (rest, planes), results = lax.scan(scalar_step, _split(state), ops)
+        return _stack(rest, planes), results
 
     def step(st, op):
         t, k, v = op
@@ -994,9 +1065,7 @@ def apply_ops(state: SkipListState, op_types: jax.Array, keys: jax.Array,
             return s2, okk.astype(jnp.int32)
         return lax.switch(t, [do_read, do_ins, do_del], st)
 
-    return lax.scan(step, state,
-                    (op_types.astype(jnp.int32), keys.astype(jnp.int32),
-                     vals.astype(jnp.int32)))
+    return lax.scan(step, state, ops)
 
 
 # ---------------------------------------------------------------------------
@@ -1076,14 +1145,11 @@ def sorted_live_kv(state: SkipListState) -> Tuple[jax.Array, jax.Array]:
 
 def to_sorted_keys(state: SkipListState, max_n: int) -> jax.Array:
     """Walk level 0 and return keys in order (KEY_MAX padded), for tests."""
+    gather = _table_gather(state)[0]
+
     def body(i, carry):
         x, out = carry
-        if state.foresight:
-            ptr, fk = _gather_fused(state.fused, jnp.zeros((1,), jnp.int32),
-                                    x[None])
-        else:
-            ptr, fk = _gather_base(state.nxt, state.keys,
-                                   jnp.zeros((1,), jnp.int32), x[None])
+        ptr, fk = gather(jnp.zeros((1,), jnp.int32), x[None])
         out = out.at[i].set(fk[0])
         return ptr[0], out
 
@@ -1117,14 +1183,11 @@ def range_scan(state: SkipListState, lo: jax.Array, hi: jax.Array,
     keys_out = jnp.full((max_out,), KEY_MAX, jnp.int32)
     vals_out = jnp.full((max_out,), NULL_VAL, jnp.int32)
 
+    gather = _table_gather(state)[0]
+
     def body(i, carry):
         x, keys_out, vals_out, count = carry
-        if state.foresight:
-            ptr, k = _gather_fused(state.fused, jnp.zeros((1,), jnp.int32),
-                                   x[None])
-        else:
-            ptr, k = _gather_base(state.nxt, state.keys,
-                                  jnp.zeros((1,), jnp.int32), x[None])
+        ptr, k = gather(jnp.zeros((1,), jnp.int32), x[None])
         ptr, k = ptr[0], k[0]
         take = (k >= lo) & (k < hi)
         keys_out = keys_out.at[i].set(jnp.where(take, k, keys_out[i]))
@@ -1151,7 +1214,9 @@ def _fat_range_scan(state: SkipListState, lo: jax.Array, hi: jax.Array,
     node + max_out emissions + one hop per visited node.
     """
     Bw = state.node_width
-    x, _preds, _s, _g = _search_loop(state, lo[None], 0)
+    gather, per_step = _table_gather(state)
+    x, _preds, _s, _g = _search_loop(gather, per_step, state.levels,
+                                     lo[None], 0)
     keys_out = jnp.full((max_out,), KEY_MAX, jnp.int32)
     vals_out = jnp.full((max_out,), NULL_VAL, jnp.int32)
     bound = 2 * max_out + Bw + 4
@@ -1161,13 +1226,7 @@ def _fat_range_scan(state: SkipListState, lo: jax.Array, hi: jax.Array,
         lane_c = jnp.minimum(lane, Bw - 1)
         k = state.fat_keys[node, lane_c]
         v = state.fat_vals[node, lane_c]
-        if state.foresight:
-            ptr, _ = _gather_fused(state.fused, jnp.zeros((1,), jnp.int32),
-                                   node[None])
-        else:
-            ptr, _ = _gather_base(state.nxt, state.keys,
-                                  jnp.zeros((1,), jnp.int32), node[None])
-        ptr = ptr[0]
+        ptr = gather(jnp.zeros((1,), jnp.int32), node[None])[0][0]
         at_end = (k == KEY_MAX) | (lane >= Bw)
         hop = at_end & (ptr != node) & ~done
         # tail self-loop, or a LIVE lane at/past hi (padding must hop)

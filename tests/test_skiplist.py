@@ -239,3 +239,120 @@ def test_range_scan_empty_and_truncated():
     ks, vs, count = sl.range_scan(st, jnp.int32(lo), jnp.int32(hi), 8)
     assert int(count) == 8
     assert np.asarray(ks).tolist() == keys[:8].tolist()
+
+
+# ---------------------------------------------------------------------------
+# apply_ops against the public single-op functions
+# ---------------------------------------------------------------------------
+
+R, I, D = sl.OP_READ, sl.OP_INSERT, sl.OP_DELETE
+
+
+def _mixed_batch(case, keys):
+    """(ops, keys) of one batch; ``keys`` are the built list's keys."""
+    a, b, c = (int(k) for k in keys[:3])
+    have = set(keys.tolist())
+    gaps = [k + 1 for k in keys.tolist() if k + 1 not in have]
+    new = gaps[::max(1, len(gaps) // 8)][:8]   # absent keys, spread out
+    if case == "upserts":        # existing keys, then a new key twice
+        return [(I, a), (I, new[0]), (R, new[0]), (I, new[0]), (I, b),
+                (R, b), (I, new[1]), (I, a), (R, new[1])]
+    if case == "delete_inserted":  # keys inserted earlier in the batch
+        return [(I, new[0]), (I, new[1]), (D, new[0]), (R, new[0]),
+                (D, new[0]), (I, new[2]), (D, new[1]), (D, new[2]),
+                (I, new[0]), (D, new[3]), (R, new[0])]
+    if case == "read_deleted":   # keys deleted earlier, slots reused
+        return [(D, a), (R, a), (D, b), (R, b), (D, a), (I, new[0]),
+                (I, a), (R, a), (R, b), (D, c), (I, new[1]), (R, c)]
+    # "exhaustion": more inserts than free slots; a delete frees one
+    return ([(I, k) for k in new[:6]] + [(R, new[5]), (D, a), (I, new[6]),
+                                         (I, new[7]), (R, new[6])])
+
+
+def _sequential(st, ops, ks, vs):
+    """The batch one op at a time through ``search``, ``insert``, ``delete``."""
+    find = jax.jit(lambda s, k: sl.search(s, k[None]).found[0])
+    ins, dele = jax.jit(sl.insert), jax.jit(sl.delete)
+    results = []
+    for t, k, v in zip(ops, ks, vs):
+        k, v = jnp.int32(k), jnp.int32(v)
+        if t == I:
+            st, ok = ins(st, k, v)
+        elif t == D:
+            st, ok = dele(st, k)
+        else:
+            ok = find(st, k)
+        results.append(int(ok))
+    return st, results
+
+
+@pytest.mark.parametrize("foresight", [True, False])
+@pytest.mark.parametrize("case", ["upserts", "delete_inserted",
+                                  "read_deleted", "exhaustion"])
+def test_apply_ops_matches_single_ops(case, foresight):
+    """The scan over the table's planes leaves the state, field by field,
+    that the public single ops leave, with the same results."""
+    full = case == "exhaustion"
+    st, keys = _build(n=10 if full else 40, cap=14 if full else 128,
+                      levels=6, foresight=foresight, seed=7)
+    batch = _mixed_batch(case, keys)
+    ops = [t for t, _ in batch]
+    ks = [k for _, k in batch]
+    vs = [1000 + i for i in range(len(batch))]
+    got, res = jax.jit(sl.apply_ops)(st, jnp.asarray(ops, jnp.int32),
+                                     jnp.asarray(ks, jnp.int32),
+                                     jnp.asarray(vs, jnp.int32))
+    want, want_res = _sequential(st, ops, ks, vs)
+    assert np.asarray(res).tolist() == want_res
+    for field in sl.SkipListState._fields:
+        g, w = getattr(got, field), getattr(want, field)
+        assert (g is None) == (w is None), field
+        if w is not None:
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                          err_msg=field)
+    oracle = DictOracle()
+    for k in keys.tolist():
+        oracle.insert(k, k * 2)
+    expect = []
+    for t, k, v in zip(ops, ks, vs):
+        if t == I:
+            expect.append(int(oracle.insert(k, v)))
+        elif t == D:
+            expect.append(int(oracle.delete(k)))
+        else:
+            expect.append(int(oracle.search(k)[0]))
+    if full:   # 2 free slots: 2 of the 6 new keys fit; the delete frees
+        # a slot for one more insert, and the insert after it fails
+        assert want_res == [1, 1, 0, 0, 0, 0, 0, 1, 1, 0, 1]
+        assert int(got.n) == 12
+    else:
+        assert want_res == expect
+        assert int(got.n) == len(oracle.d)
+    if foresight:
+        assert bool(sl.check_foresight_invariant(got))
+
+
+@pytest.mark.parametrize("foresight", [True, False])
+def test_apply_ops_sharded_matches_monolithic_mixed_batch(foresight):
+    """``apply_ops_sharded`` (``jax.vmap(apply_ops)`` over the shards) on
+    the mixed batches above gives the monolithic list's results and keys."""
+    from repro.core import sharded as shd
+    mono, keys = _build(n=40, cap=128, levels=6, foresight=foresight, seed=7)
+    shl = shd.build_sharded(jnp.asarray(keys), jnp.asarray(keys * 2),
+                            n_shards=4, capacity=64, levels=6,
+                            foresight=foresight, seed=7)
+    batch = sum((_mixed_batch(c, keys) for c in
+                 ("upserts", "delete_inserted", "read_deleted")), [])
+    ops = jnp.asarray([t for t, _ in batch], jnp.int32)
+    ks = jnp.asarray([k for _, k in batch], jnp.int32)
+    vs = jnp.arange(len(batch), dtype=jnp.int32) + 1000
+    mono2, res_m = jax.jit(sl.apply_ops)(mono, ops, ks, vs)
+    shl2, res_s = jax.jit(shd.apply_ops_sharded)(shl, ops, ks, vs)
+    np.testing.assert_array_equal(np.asarray(res_s), np.asarray(res_m))
+    assert bool(shd.check_sharded_invariant(shl2))
+    assert int(shd.total_n(shl2)) == int(mono2.n)
+    q = jnp.concatenate([ks, jnp.asarray(keys)])
+    f_m, v_m = sl.search_fast(mono2, q)
+    f_s, v_s = shd.search_sharded(shl2, q)
+    np.testing.assert_array_equal(np.asarray(f_s), np.asarray(f_m))
+    np.testing.assert_array_equal(np.asarray(v_s), np.asarray(v_m))

@@ -15,6 +15,7 @@ import importlib
 import importlib.util
 import math
 import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -91,6 +92,82 @@ def test_store_paths_compile_at_smoke_size(one_chip):
     write = jax.jit(sl.apply_ops, donate_argnums=(0,)).lower(
         state, op, op, op).compile()
     _fits(write)
+
+
+_HLO_ARRAY = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\((.*)$")
+
+
+def _table_sized_in_loops(hlo: str, elems: int) -> list:
+    """Instructions of ``elems`` or more elements inside a ``while`` body of
+    compiled HLO text that move data: every array-shaped instruction but a
+    loop parameter, tuple element, bitcast or (fused) scatter, which
+    updates its operand in place."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif cur is not None and line.startswith("  "):
+            cur.append(line)
+
+    def root_op(name):
+        for line in comps[name]:
+            m = re.match(r"\s*ROOT %?[\w.\-]+ = \S+ ([\w\-]+)\(", line)
+            if m:
+                return m.group(1)
+
+    found = []
+    for body in sorted(set(re.findall(r"body=%?([\w.\-]+)", hlo))):
+        for line in comps[body]:
+            m = _HLO_ARRAY.match(line)
+            if not m:
+                continue
+            name, dims, op, rest = m.groups()
+            if math.prod(int(d) for d in dims.split(",") if d) < elems:
+                continue
+            if op in ("parameter", "get-tuple-element", "bitcast", "scatter"):
+                continue
+            if op == "fusion" and root_op(re.search(
+                    r"calls=%?([\w.\-]+)", rest).group(1)) == "scatter":
+                continue
+            found.append(f"{body}: {name} = {op}[{dims}]")
+    return found
+
+
+def test_store_apply_copies_no_table_in_its_loops(one_chip):
+    """The donated ``apply_ops`` at ``ycsb_store_4m.ycsb_d``'s shape (4M
+    keys, 24 levels, capacity 2^23, 13 inserts a call): no loop body
+    copies, relays out or selects the table; only scatters write it.  The
+    flat view of the fused table made one copy of it per search step, with
+    3,222,485,504 bytes of temporaries."""
+    n, levels, cap, ops = 4_000_000, 24, 2**23, 13
+    state = _on(one_chip, jax.eval_shape(
+        functools.partial(sl.build, capacity=cap, levels=levels),
+        _spec(one_chip, (n,)), _spec(one_chip, (n,))))
+    op = _spec(one_chip, (ops,))
+    write = jax.jit(sl.apply_ops, donate_argnums=(0,)).lower(
+        state, op, op, op).compile()
+    _fits(write)
+    assert _table_sized_in_loops(write.as_text(), levels * cap) == []
+    assert write.memory_analysis().temp_size_in_bytes <= 3_222_485_504
+
+
+def test_page_table_apply_copies_no_table_in_its_loops(one_chip):
+    """``jax.vmap(apply_ops)`` over the page table's stacked shards (8
+    shards x 16 levels x 32,768 slots, windows of 32 ops), as
+    ``apply_ops_sharded`` runs it: no loop body copies or selects the
+    table, where the vmapped switch copied it 3 times per scan position."""
+    shards, levels, cap, window = 8, 16, 32768, 32
+    stacked = _on(one_chip, jax.eval_shape(functools.partial(
+        shd.empty_sharded, n_shards=shards, capacity=cap,
+        levels=levels)).shards)
+    op = _spec(one_chip, (shards, window))
+    write = jax.jit(jax.vmap(sl.apply_ops)).lower(stacked, op, op,
+                                                  op).compile()
+    _fits(write)
+    assert _table_sized_in_loops(write.as_text(),
+                                 shards * levels * cap) == []
 
 
 def test_search_sharded_compiles(one_chip):
