@@ -15,16 +15,22 @@ Data path (inside ``shard_map``, per device)
 1. route my chunk of the global batch over the replicated
    ``device_boundaries`` (destination device per lane);
 2. stable-sort lanes by destination and slice per-destination segments
-   (``sharded.shard_segments`` — the same primitive the single-device
-   batch apply uses);
+   (the bounds ``sharded.shard_segments`` gives the single-device batch
+   apply, here from per-destination counts: two device ops where two
+   binary searches over the lanes run a loop each);
 3. ``lax.all_to_all`` the route-sorted lanes so every lane lands on its
    owning device (dead bucket slots carry no-op fills);
 4. run the EXISTING single-device engine on the received lanes —
-   ``search_sharded`` / ``apply_ops_sharded`` here, the clustered
+   ``search_sharded_counted`` / ``apply_ops_sharded`` here, the clustered
    ``pallas_call`` in ``kernels.mesh_launch``;
 5. ``all_to_all`` the results back and inverse-permute into the original
    lane order — bit-identical to running the single-device engine on the
    whole batch.
+
+Each entry point is one jitted program, padding and slicing included.
+``read_rows_mesh`` adds a second exchange to the lookup: each found row
+id goes on to the device that holds its row block (``place_rows``), the
+row is taken there and comes back in lane order.
 
 Linearization: the arriving lanes on each device are ordered (source
 device, original position) — exactly the restriction of the global batch
@@ -47,6 +53,7 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding
 
@@ -54,8 +61,8 @@ from repro.core.rebalance_traced import DeviceLoadStats, cross_device_load
 from repro.core.sharded import (HIGH_WATER, LOW_WATER, ShardedSkipList,
                                 apply_ops_sharded, build_sharded,
                                 check_sharded_invariant, partition_boundaries,
-                                route, search_sharded, shard_capacity_for,
-                                shard_segments, total_n)
+                                route, search_sharded_counted,
+                                shard_capacity_for, total_n)
 from repro.core.skiplist import KEY_MAX, KEY_MIN, NULL_VAL, OP_READ
 from repro.parallel.sharding import (INDEX_AXIS, index_batch_spec,
                                      index_replicated_spec, index_state_spec)
@@ -118,10 +125,10 @@ def build_mesh_index(keys: jax.Array, vals: jax.Array, *, mesh,
     shared static ``capacity`` (auto-sized for ``m`` over ``n_shards``
     when 0).  The global ``device_boundaries`` come from the same
     ``partition_boundaries`` stride rule as the per-shard boundaries.
-    Eager build (like ``build_sharded`` it is called once per index
-    lifetime); the result feeds the jitted ``search_mesh`` /
-    ``apply_ops_mesh`` data path.  Each slice is built on, and stays on,
-    its own device of the ``mesh`` it will run on (``_assemble``).
+    Called once per index lifetime, like ``build_sharded``; the result
+    feeds the jitted ``search_mesh`` / ``apply_ops_mesh`` data path.  Each
+    slice is built on, and stays on, its own device of the ``mesh`` it
+    will run on, all in one program (``_assemble``).
     """
     D = int(n_devices)
     if D < 1:
@@ -139,14 +146,9 @@ def build_mesh_index(keys: jax.Array, vals: jax.Array, *, mesh,
         vals = jnp.concatenate([vals, jnp.full((pad,), NULL_VAL, jnp.int32)])
         valid = jnp.concatenate([valid, jnp.zeros((pad,), jnp.bool_)])
 
-    def slice_d(d):
-        return build_sharded(
-            keys[d * m:(d + 1) * m], vals[d * m:(d + 1) * m],
-            n_shards=n_shards, capacity=capacity, levels=levels,
-            foresight=foresight, seed=seed + d * n_shards,
-            valid=valid[d * m:(d + 1) * m], node_width=node_width)
-
-    return _assemble(slice_d, D, partition_boundaries(keys, m), mesh)
+    return _assemble(keys, vals, valid, partition_boundaries(keys, m), mesh,
+                     n_shards=n_shards, capacity=capacity, levels=levels,
+                     foresight=foresight, seed=seed, node_width=node_width)
 
 
 def empty_mesh_index(*, mesh, n_devices: int, n_shards: int, capacity: int,
@@ -162,43 +164,55 @@ def empty_mesh_index(*, mesh, n_devices: int, n_shards: int, capacity: int,
     cache) get balanced devices by construction.  Each device starts as
     an ``empty_sharded``-style state built at ``n_shards`` (the per-
     device ceiling when applied with ``rebalance=True``), placed like
-    ``build_mesh_index``'s slices when ``mesh`` is given.
+    ``build_mesh_index``'s slices.
     """
     D = int(n_devices)
-    z = jnp.zeros((0,), jnp.int32)
     step = max(1, key_span // D)
     db = (jnp.arange(D, dtype=jnp.int32) * step).at[0].set(KEY_MIN)
-    return _assemble(
-        lambda d: build_sharded(z, z, n_shards=n_shards, capacity=capacity,
-                                levels=levels, foresight=foresight,
-                                seed=seed + d * n_shards,
-                                node_width=node_width),
-        D, db, mesh)
+    # one dead key a device: each builds from nothing, as empty_sharded
+    return _assemble(jnp.full((D,), KEY_MAX, jnp.int32),
+                     jnp.full((D,), NULL_VAL, jnp.int32),
+                     jnp.zeros((D,), jnp.bool_), db, mesh,
+                     n_shards=n_shards, capacity=capacity, levels=levels,
+                     foresight=foresight, seed=seed, node_width=node_width)
 
 
-def _assemble(slice_d, D: int, device_boundaries: jax.Array,
-              mesh) -> MeshShardedIndex:
-    """Stack the ``D`` device slices ``slice_d(d)`` into one index.
+@functools.lru_cache(maxsize=None)
+def _build_fn(mesh, n_shards: int, capacity: int, levels: int,
+              foresight: bool, node_width: int):
+    """Every device's ``build_sharded`` of its slice, in one program over
+    the mesh: one compile, where a build per device compiles once for
+    each device."""
+    def body(seed, keys, vals, valid):
+        st = build_sharded(keys, vals, n_shards=n_shards, capacity=capacity,
+                           levels=levels, foresight=foresight, seed=seed[0],
+                           valid=valid, node_width=node_width)
+        return jax.tree.map(lambda a: a[None], st)
+
+    spec = index_state_spec()
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(spec,) * 4,
+                                 out_specs=spec, check_vma=False))
+
+
+def _assemble(keys: jax.Array, vals: jax.Array, valid: jax.Array,
+              device_boundaries: jax.Array, mesh, *, n_shards: int,
+              capacity: int, levels: int, foresight: bool, seed: int,
+              node_width: int) -> MeshShardedIndex:
+    """Build device ``d``'s slice from the ``d``-th of ``D`` equal blocks
+    of ``keys``, ``vals`` and ``valid``, at seed ``seed + d * n_shards``.
 
     Each slice is built on, and stays on, its own device of the ``mesh``'s
     ``index`` axis: the stacked leaves carry a ``NamedSharding`` over that
     axis, so the jitted data path finds every shard where it already
     lives.
     """
+    D = int(device_boundaries.shape[0])
     if D != mesh.shape[INDEX_AXIS]:
         raise ValueError(f"n_devices={D} but the mesh's {INDEX_AXIS!r} "
                          f"axis has {mesh.shape[INDEX_AXIS]} devices")
-    devices = list(mesh.devices.reshape(-1))
-    states = []
-    for dev in devices:
-        with jax.default_device(dev):
-            st = slice_d(len(states))
-        states.append(jax.device_put(st, dev))   # committed to ``dev``
-    sharding = NamedSharding(mesh, index_state_spec())
-    local = jax.tree.map(
-        lambda *xs: jax.make_array_from_single_device_arrays(
-            (D,) + xs[0].shape, sharding, [x[None] for x in xs]),
-        *states)
+    seeds = seed + n_shards * jnp.arange(D, dtype=jnp.int32)
+    local = _build_fn(mesh, n_shards, capacity, levels, bool(foresight),
+                      node_width)(seeds, keys, vals, valid)
     db = jax.device_put(device_boundaries,
                         NamedSharding(mesh, index_replicated_spec()))
     return MeshShardedIndex(local, db)
@@ -220,7 +234,10 @@ def _exchange_out(did: jax.Array, payloads, fills, D: int):
     C = did.shape[0]
     perm = jnp.argsort(did, stable=True)
     did_s = jnp.take(did, perm)
-    starts, lens = shard_segments(did_s, D)
+    # the bounds ``sharded.shard_segments`` finds by binary search, from
+    # counts: destination d's lanes follow those of every lower one
+    lens = jnp.zeros((D,), jnp.int32).at[did].add(1)
+    starts = jnp.cumsum(lens) - lens
     idx = jnp.clip(starts[:, None] + jnp.arange(C)[None, :], 0, C - 1)
     valid = jnp.arange(C)[None, :] < lens[:, None]            # [D, C]
     received = []
@@ -238,19 +255,20 @@ def _exchange_back(result: jax.Array, perm: jax.Array, starts: jax.Array,
                    did_s: jax.Array, D: int) -> jax.Array:
     """Send per-lane results back to their source and restore lane order.
 
-    ``result`` is ``[D * C]`` in the received (source-major) layout; after
-    the return ``all_to_all``, row ``b`` of the ``[D, C]`` buffer holds my
-    bucket-``b`` lanes' results in bucket order, so the sorted-order
+    ``result`` is ``[D * C, ...]`` (a row of any shape per lane) in the
+    received (source-major) layout; after the return ``all_to_all``, row
+    ``b`` of the ``[D, C, ...]`` buffer holds my bucket-``b`` lanes'
+    results in bucket order, so the sorted-order
     result is ``back[did_s[j], j - starts[did_s[j]]]`` and the inverse
     permutation undoes the route-sort — the round trip is the identity on
     lane order.
     """
     C = did_s.shape[0]
-    back = lax.all_to_all(result.reshape(D, C), INDEX_AXIS, split_axis=0,
-                          concat_axis=0)
+    back = lax.all_to_all(result.reshape((D, C) + result.shape[1:]),
+                          INDEX_AXIS, split_axis=0, concat_axis=0)
     j = jnp.arange(C)
     res_sorted = back[did_s, j - starts[did_s]]
-    return jnp.take(res_sorted, jnp.argsort(perm))
+    return jnp.take(res_sorted, jnp.argsort(perm), axis=0)
 
 
 def _chunk(arrs, B: int, D: int, fills):
@@ -283,45 +301,161 @@ def _validate(mx: MeshShardedIndex, mesh) -> int:
 # The jitted collective data paths
 # ---------------------------------------------------------------------------
 
-@functools.lru_cache(maxsize=None)
-def _search_fn(mesh):
+class MeshReadCounts(NamedTuple):
+    """What each device's traversal did in one mesh read, for
+    ``repro.obs.count_mesh_read``: ``steps`` [D, S], the steps of each of
+    the ``S`` slots a device received and searched; ``live`` [D, S], the
+    slots that hold a lane of the batch padded to a multiple of ``D`` (the
+    others are bucket fill)."""
+
+    steps: jax.Array
+    live: jax.Array
+
+
+def _search_map(mesh):
+    """Per-device search under ``shard_map``: route my lanes, exchange,
+    ``search_sharded_counted`` on the received lanes, results back in my
+    lane order; and each received slot's steps and whether it is live."""
     D = int(mesh.shape[INDEX_AXIS])
 
     def body(local, db, q):
         local = jax.tree.map(lambda a: a[0], local)
         did = route(db, q)
-        (rq,), _, perm, starts, did_s = _exchange_out(
+        (rq,), live, perm, starts, did_s = _exchange_out(
             did, (q,), (jnp.int32(0),), D)
-        found, vals = search_sharded(local, rq)
+        found, vals, steps = search_sharded_counted(local, rq)
         found_b = _exchange_back(found.astype(jnp.int32), perm, starts,
                                  did_s, D)
         vals_b = _exchange_back(vals, perm, starts, did_s, D)
-        return found_b.astype(jnp.bool_), vals_b
+        return found_b.astype(jnp.bool_), vals_b, steps, live
 
-    fn = jax.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(index_state_spec(), index_replicated_spec(),
                   index_batch_spec()),
-        out_specs=(index_batch_spec(), index_batch_spec()),
-        check_vma=False)
-    return jax.jit(fn)
+        out_specs=(index_batch_spec(),) * 4, check_vma=False)
+
+
+def _gather_map(mesh):
+    """Per-device row gather under ``shard_map``: send each lane's row id
+    to the device that holds its row block, ``take`` there, and the rows
+    come back in my lane order."""
+    D = int(mesh.shape[INDEX_AXIS])
+
+    def body(rows, rid):
+        m = rows.shape[0]
+        owner = jnp.clip(rid // m, 0, D - 1)
+        (local,), _, perm, starts, did_s = _exchange_out(
+            owner, (rid - owner * m,), (jnp.int32(0),), D)
+        return _exchange_back(jnp.take(rows, local, axis=0), perm, starts,
+                              did_s, D)
+
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=(index_batch_spec(), index_batch_spec()),
+        out_specs=index_batch_spec(), check_vma=False)
+
+
+def _search_padded(mesh):
+    """``_search_map`` on a batch padded to a multiple of ``D`` lanes:
+    (found, vals) of the padded batch, and the ``MeshReadCounts``."""
+    D = int(mesh.shape[INDEX_AXIS])
+    search = _search_map(mesh)
+
+    def run(local, db, queries):
+        q = queries.astype(jnp.int32)
+        (qp,), _ = _chunk((q,), q.shape[0], D, (jnp.int32(0),))
+        found, vals, steps, live = search(local, db, qp)
+        return found, vals, MeshReadCounts(steps.reshape(D, -1),
+                                           live.reshape(D, -1))
+
+    return run
+
+
+@functools.lru_cache(maxsize=None)
+def _search_fn(mesh):
+    """The whole mesh lookup as one jitted program: pad, search, slice
+    the padding off."""
+    search = _search_padded(mesh)
+
+    def run(local, db, queries):
+        found, vals, counts = search(local, db, queries)
+        B = queries.shape[0]
+        return found[:B], vals[:B], counts
+
+    return jax.jit(run)
+
+
+def search_mesh_counted(mx: MeshShardedIndex, queries: jax.Array, *, mesh
+                        ) -> Tuple[jax.Array, jax.Array, MeshReadCounts]:
+    """Batched lookup across the whole mesh: (found, vals, counts).
+
+    Routes each lane to its owning device, exchanges via ``all_to_all``,
+    runs the single-device traversal loop on the received lanes, and
+    inverse-permutes results back — bit-identical to ``search_sharded``
+    on an equivalent single-device index.  One launch.  ``counts`` says
+    what each device's loop did (``MeshReadCounts``).
+    """
+    _validate(mx, mesh)
+    return _search_fn(mesh)(mx.local, mx.device_boundaries, queries)
 
 
 def search_mesh(mx: MeshShardedIndex, queries: jax.Array, *, mesh
                 ) -> Tuple[jax.Array, jax.Array]:
-    """Batched lookup across the whole mesh: (found, vals).
+    """``search_mesh_counted``'s (found, vals)."""
+    return search_mesh_counted(mx, queries, mesh=mesh)[:2]
 
-    Routes each lane to its owning device, exchanges via ``all_to_all``,
-    runs the single-device ``search_sharded`` loop on the received lanes,
-    and inverse-permutes results back — bit-identical to
-    ``search_sharded`` on an equivalent single-device index.
+
+def place_rows(rows, mesh, n_keys: int) -> jax.Array:
+    """Table rows ``[n_keys, ...]`` as one ``[D * m, ...]`` array sharded
+    over the ``index`` axis in blocks of ``m = ceil(n_keys / D)``: row
+    block ``d`` on the device of key slice ``d`` (``build_mesh_index``'s
+    stride).  Rows already placed that way are returned as they are, so a
+    caller can make each block on its device and no device ever holds the
+    whole table; others are padded with zero rows and placed."""
+    D = int(mesh.shape[INDEX_AXIS])
+    m = max(1, -(-n_keys // D))
+    sharding = NamedSharding(mesh, index_batch_spec())
+    if isinstance(rows, jax.Array) and rows.shape[0] == D * m and \
+            rows.sharding.is_equivalent_to(sharding, rows.ndim):
+        return rows
+    if rows.shape[0] != n_keys:
+        raise ValueError(f"{rows.shape[0]} rows for {n_keys} keys: give one "
+                         f"row per key, or {D * m} rows placed in {D} "
+                         f"blocks of {m} over the '{INDEX_AXIS}' axis")
+    rows = np.asarray(rows)
+    pad = np.zeros((D * m - n_keys,) + rows.shape[1:], rows.dtype)
+    return jax.device_put(np.concatenate([rows, pad]), sharding)
+
+
+@functools.lru_cache(maxsize=None)
+def _read_rows_fn(mesh):
+    """``_search_fn``'s program with the row gather of the keys found
+    after the search: each lane's row id, left on the device of its lane,
+    goes on to the device of its row.  Missing keys read row 0, as the
+    one-device store reads them."""
+    search, gather = _search_padded(mesh), _gather_map(mesh)
+
+    def run(local, db, rows, queries):
+        found, rid, counts = search(local, db, queries)
+        got = gather(rows, jnp.where(found, rid, 0))
+        B = queries.shape[0]
+        return got[:B], found[:B], counts
+
+    return jax.jit(run)
+
+
+def read_rows_mesh(mx: MeshShardedIndex, rows: jax.Array,
+                   queries: jax.Array, *, mesh
+                   ) -> Tuple[jax.Array, jax.Array, MeshReadCounts]:
+    """(rows of the keys, found, counts) for a batch of keys, in one
+    launch; ``counts`` as ``search_mesh_counted``'s.
+
+    ``mx``'s values are row ids into ``rows``, placed by ``place_rows``;
+    a row id may point into any device's block (an insert may name any
+    row), so the gather routes by the row's owner.
     """
-    D = _validate(mx, mesh)
-    q = queries.astype(jnp.int32)
-    B = q.shape[0]
-    (qp,), _ = _chunk((q,), B, D, (jnp.int32(0),))
-    found, vals = _search_fn(mesh)(mx.local, mx.device_boundaries, qp)
-    return found[:B], vals[:B]
+    _validate(mx, mesh)
+    return _read_rows_fn(mesh)(mx.local, mx.device_boundaries, rows, queries)
 
 
 @functools.lru_cache(maxsize=None)
@@ -357,7 +491,20 @@ def _apply_fn(mesh, rebalance, high_water, low_water, max_shards,
         out_specs=(index_state_spec(), index_batch_spec(),
                    index_batch_spec(), index_batch_spec()),
         check_vma=False)
-    return jax.jit(fn)
+
+    def run(local, db, op_types, keys, vals, seed):
+        ops = op_types.astype(jnp.int32)
+        keys = keys.astype(jnp.int32)
+        vals = vals.astype(jnp.int32)
+        B = keys.shape[0]
+        (opp, keyp, valp), _ = _chunk(
+            (ops, keys, vals), B, D,
+            (jnp.int32(OP_READ), jnp.int32(0), jnp.int32(0)))
+        new_local, res, live, routed = fn(
+            local, db, opp, keyp, valp, jnp.asarray(seed, jnp.int32))
+        return new_local, res[:B], cross_device_load(live, routed)
+
+    return jax.jit(run)
 
 
 def apply_ops_mesh(mx: MeshShardedIndex, op_types: jax.Array,
@@ -375,24 +522,16 @@ def apply_ops_mesh(mx: MeshShardedIndex, op_types: jax.Array,
     order, bit-identical to the single-device apply; the third return is
     the :class:`~repro.core.rebalance_traced.DeviceLoadStats` counter
     pack surfacing cross-device imbalance (which device-local rebalancing
-    deliberately cannot fix).
+    deliberately cannot fix).  One launch.
     """
-    D = _validate(mx, mesh)
-    ops = op_types.astype(jnp.int32)
-    keys = keys.astype(jnp.int32)
-    vals = vals.astype(jnp.int32)
-    B = keys.shape[0]
-    (opp, keyp, valp), _ = _chunk(
-        (ops, keys, vals), B, D,
-        (jnp.int32(OP_READ), jnp.int32(0), jnp.int32(0)))
+    _validate(mx, mesh)
     fn = _apply_fn(mesh, bool(rebalance), float(high_water),
                    float(low_water), int(max_shards), int(max_segment))
-    new_local, res, live, routed = fn(
-        mx.local, mx.device_boundaries, opp, keyp, valp,
-        jnp.asarray(seed, jnp.int32))
+    new_local, res, stats = fn(mx.local, mx.device_boundaries, op_types,
+                               keys, vals, seed)
     new_mx = MeshShardedIndex(local=new_local,
                               device_boundaries=mx.device_boundaries)
-    return new_mx, res[:B], cross_device_load(live, routed)
+    return new_mx, res, stats
 
 
 # ---------------------------------------------------------------------------

@@ -25,6 +25,16 @@ the result flags).  ``max_shards`` caps rebalancing growth — and doubles
 as the static ceiling a jit-driven caller pads the index to
 (``core.rebalance_traced.pad_shards``) so traced in-place splits keep
 working inside one compiled trace.
+
+Past one device's memory, ``mesh_devices`` >= 2 partitions the key space
+over a ``D``-device mesh (``core.mesh_index``): each device holds one key
+slice's index (``n_shards`` range shards, auto = 1) and the block of rows
+of the same stride (``mesh_index.place_rows``), so no device holds the
+whole table.  Lookups, reads and updates route lanes to their owning
+device by ``all_to_all``; a read gathers each row on the device that holds
+it, since an insert may name a row in any block.  Each device's shards
+keep their fixed capacity (no rebalancing across or within devices), and
+``range_scan`` is not served.
 """
 from __future__ import annotations
 
@@ -36,9 +46,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.core import mesh_index as mi
 from repro.core import sharded as shd
 from repro.core import skiplist as sl
 from repro.kernels import ops as kops
+from repro.launch.mesh import make_index_mesh
 
 
 @dataclasses.dataclass
@@ -65,6 +77,10 @@ class StoreConfig:
     repack_every: int = 0    # update batches between amortized repacks
                              # (0 = never; sharded + rebalance only)
     seed: int = 0
+    mesh_devices: int = 1    # 1 = one device; >= 2 = index and rows
+                             # partitioned by key range over a D-device
+                             # mesh (XLA engine; n_shards is then per
+                             # device)
 
 
 _apply_donated = jax.jit(sl.apply_ops, donate_argnums=(0,))
@@ -73,10 +89,12 @@ _apply_donated = jax.jit(sl.apply_ops, donate_argnums=(0,))
 class IndexedSampleStore:
     """rows: [N, seq_len+1] tokens; index: key -> row (Foresight skiplist)."""
 
-    index: Union[sl.SkipListState, shd.ShardedSkipList]
+    index: Union[sl.SkipListState, shd.ShardedSkipList, mi.MeshShardedIndex]
 
-    def __init__(self, cfg: StoreConfig, rows: Optional[np.ndarray] = None,
+    def __init__(self, cfg: StoreConfig, rows=None,
                  keys: Optional[np.ndarray] = None):
+        """``rows``: [n_samples, seq_len+1] int32, or on a mesh store also
+        rows already placed as ``mesh_index.place_rows`` places them."""
         self.cfg = cfg
         rng = np.random.default_rng(cfg.seed)
         if rows is None:
@@ -84,8 +102,12 @@ class IndexedSampleStore:
                                   cfg.vocab)
         if keys is None:
             keys = np.sort(rng.choice(2**30, cfg.n_samples, replace=False))
-        self.rows = jnp.asarray(rows, jnp.int32)
         self.keys_np = keys.astype(np.int64)
+        self.mesh = None
+        if cfg.mesh_devices >= 2:
+            self._build_mesh(rows, keys)
+            return
+        self.rows = jnp.asarray(rows, jnp.int32)
         cap = int(2 ** np.ceil(np.log2(cfg.n_samples * 2 + 4)))
         self.n_shards = cfg.n_shards
         if self.n_shards == 0:
@@ -111,6 +133,21 @@ class IndexedSampleStore:
                 capacity=cap, levels=cfg.index_levels,
                 foresight=cfg.foresight, seed=cfg.seed)
 
+    def _build_mesh(self, rows, keys: np.ndarray) -> None:
+        cfg = self.cfg
+        if cfg.use_kernel:
+            raise ValueError("a mesh store (mesh_devices >= 2) runs the XLA "
+                             "engine: use_kernel must be False")
+        self.mesh = make_index_mesh(cfg.mesh_devices)
+        self.n_shards = cfg.n_shards or 1
+        self.rows = mi.place_rows(rows, self.mesh, cfg.n_samples)
+        self.index = mi.build_mesh_index(
+            jnp.asarray(keys, jnp.int32),
+            jnp.arange(cfg.n_samples, dtype=jnp.int32),  # value = row id
+            mesh=self.mesh, n_devices=cfg.mesh_devices,
+            n_shards=self.n_shards, levels=cfg.index_levels,
+            foresight=cfg.foresight, seed=cfg.seed)
+
     @property
     def sharded(self) -> bool:
         return isinstance(self.index, shd.ShardedSkipList)
@@ -123,6 +160,12 @@ class IndexedSampleStore:
         Under a profiler trace the XLA traversals add their loop counts to
         ``obs``'s counters."""
         n = keys.shape[0]
+        if self.mesh is not None:
+            with obs.span("read.search_mesh", ops=n):
+                found, vals, counts = mi.search_mesh_counted(
+                    self.index, keys, mesh=self.mesh)
+            self._count_mesh(counts)
+            return found, vals
         if self.cfg.use_kernel:
             r = kops.search_kernel(self.index, keys,   # auto-dispatches
                                    cluster=self.cfg.clustered)
@@ -144,14 +187,37 @@ class IndexedSampleStore:
         """Fetch token rows for keys (missing keys fall back to row 0)."""
         n = keys.shape[0]
         with obs.span("store.get_batch", ops=n):
+            if self.mesh is not None:
+                return self._mesh_get_batch(keys)
             found, row_ids = self.lookup(keys)
             with obs.span("store.gather_rows", ops=n):
                 safe = jnp.where(found, row_ids, 0)
                 return self.rows[safe], found
 
+    def _mesh_get_batch(self, keys: jax.Array
+                        ) -> Tuple[jax.Array, jax.Array]:
+        """One launch: the search and the row gather run in one program,
+        so both spans hold it."""
+        n = keys.shape[0]
+        with obs.span("read.search_mesh", ops=n), \
+                obs.span("store.gather_rows", ops=n):
+            rows, found, counts = mi.read_rows_mesh(
+                self.index, self.rows, keys, mesh=self.mesh)
+        self._count_mesh(counts)
+        return rows, found
+
+    def _count_mesh(self, counts: mi.MeshReadCounts) -> None:
+        """The mesh read's program counts on every call (one program,
+        traced or not); ``obs`` adds them only under a trace."""
+        obs.count_mesh_read(counts.steps, counts.live,
+                            per_step=1 if self.cfg.foresight else 2)
+
     def range_scan(self, lo, hi, max_out: int
                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
         """Ordered (key, row_id) scan of [lo, hi); crosses shard boundaries."""
+        if self.mesh is not None:
+            raise NotImplementedError(
+                "range_scan is not served on a mesh store (mesh_devices >= 2)")
         lo = jnp.asarray(lo, jnp.int32)
         hi = jnp.asarray(hi, jnp.int32)
         if self.sharded:
@@ -163,7 +229,12 @@ class IndexedSampleStore:
     def _apply(self, ops: jax.Array, keys: jax.Array, vals: jax.Array
                ) -> jax.Array:
         n = ops.shape[0]
-        if self.sharded:
+        if self.mesh is not None:
+            with obs.span("write.apply_ops_mesh", ops=n):
+                self.index, results, _ = mi.apply_ops_mesh(
+                    self.index, ops, keys, vals, mesh=self.mesh,
+                    seed=self.cfg.seed)
+        elif self.sharded:
             with obs.span("write.apply_ops_sharded", ops=n):
                 self.index, results = shd.apply_ops_sharded(
                     self.index, ops, keys, vals,

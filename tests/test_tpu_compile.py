@@ -229,3 +229,38 @@ def test_validated_kernel_compiles(one_chip):
         _spec(one_chip, (BATCH,))).compile()
     assert "tpu_custom_call" in compiled.as_text()
     _fits(compiled)
+
+
+def test_mesh_store_read_compiles_at_the_full_ycsb_table(topo):
+    """The mesh store's read (``mesh_index.read_rows_mesh``: search and
+    row gather in one program) over all four chips of the ``v5e:2x2``, at
+    the full DBx1000 YCSB table: 10,485,760 keys of 1,000-byte rows, a key
+    slice and its rows a chip (24 levels, capacity 2^23, one shard).  It
+    fits beside the table with no copy of it, and the lanes and rows move
+    by ``all_to_all`` alone: the keys out with the flags of the live slots
+    (for ``repro.obs``), found and row ids back, the row ids out and the
+    rows back."""
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.core import mesh_index as mi
+    n, D, levels, width = 10 * 2**20, 4, 24, 250
+    m = n // D
+    mesh = Mesh(topo.devices, ("index",))
+    blocks = NamedSharding(mesh, P("index"))
+    per_chip = jax.eval_shape(
+        functools.partial(shd.build_sharded, n_shards=1, levels=levels),
+        jax.ShapeDtypeStruct((m,), jnp.int32),
+        jax.ShapeDtypeStruct((m,), jnp.int32))
+    assert per_chip.shard_capacity == 2**23
+    local = jax.tree.map(lambda a: _spec(blocks, (D,) + a.shape, a.dtype),
+                         per_chip)
+    replicated = NamedSharding(mesh, P())
+    compiled = mi._read_rows_fn(mesh).lower(
+        local, _spec(replicated, (D,)), _spec(blocks, (n, width)),
+        _spec(replicated, (4096,))).compile()
+    _fits(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**24
+    hlo = compiled.as_text()
+    assert len(re.findall(r"= \S+ all-to-all\(", hlo)) == 6
+    assert not re.search(r"all-gather|all-reduce|collective-permute", hlo)
