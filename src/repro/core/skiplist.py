@@ -597,8 +597,9 @@ def search_fast_counted(state: SkipListState, queries: jax.Array
     ``L - 1``, one step a level above ``effective_top_level``, so its
     ``gathers`` exceed ``g * sum(steps)`` by exactly
     ``B * g * (L - 1 - effective_top_level)``.  The count is a
-    primitive-level add in the loop, so the eager read path lowers no
-    nested program for it.
+    primitive-level add in the loop, with no nested program in its body;
+    the served read path answers from this twin, its own jitted program
+    (``obs.Read``), only while a profiler trace records.
     """
     return _search_fast(state, queries, count=True)
 
@@ -607,8 +608,8 @@ def search_fast_counted(state: SkipListState, queries: jax.Array
 def _search_fast(state: SkipListState, queries: jax.Array, *, count: bool
                  ) -> tuple:
     """The loop of both; with ``count`` it also carries each lane's steps,
-    returned last.  Without, the eager read path lowers the smaller
-    program on every call."""
+    returned last.  Without, the smaller program: the one an untraced
+    served read runs, built once per index structure and batch shape."""
     q = queries.astype(jnp.int32)
     B = q.shape[0]
     x = jnp.zeros((B,), jnp.int32)

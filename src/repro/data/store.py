@@ -39,7 +39,7 @@ keep their fixed capacity (no rebalancing across or within devices), and
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple, Union
+from typing import NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -84,6 +84,37 @@ class StoreConfig:
 
 
 _apply_donated = jax.jit(sl.apply_ops, donate_argnums=(0,))
+
+
+def _read_rows(search):
+    """One program: ``search``'s traversal, then the row gather of the
+    keys it found (row 0 for a missing key): (rows, found[, steps])."""
+    def read_rows(index, rows, keys):
+        found, row_ids, *steps = search(index, keys)
+        return (rows[jnp.where(found, row_ids, 0)], found, *steps)
+    return read_rows
+
+
+class _Reads(NamedTuple):
+    """The one-device XLA read programs of one index kind, each an
+    ``obs.Read``: a plain program and its counted twin."""
+
+    span: str
+    lookup: obs.Read       # -> (found, row_ids)
+    read_rows: obs.Read    # -> (rows, found)
+
+
+def _reads(span: str, search, counted) -> _Reads:
+    return _Reads(span, obs.Read(search, counted),
+                  obs.Read(_read_rows(search), _read_rows(counted)))
+
+
+# keyed by ``IndexedSampleStore.sharded``: the monolithic index (its
+# preds-free read path), then the sharded one
+_READS = {False: _reads("read.search_fast", sl.search_fast,
+                        sl.search_fast_counted),
+          True: _reads("read.search_sharded", shd.search_sharded,
+                       shd.search_sharded_counted)}
 
 
 class IndexedSampleStore:
@@ -170,18 +201,9 @@ class IndexedSampleStore:
             r = kops.search_kernel(self.index, keys,   # auto-dispatches
                                    cluster=self.cfg.clustered)
             return r.found, r.vals
-        if self.sharded:
-            name, plain, counted = ("read.search_sharded", shd.search_sharded,
-                                    shd.search_sharded_counted)
-        else:   # preds-free read path
-            name, plain, counted = ("read.search_fast", sl.search_fast,
-                                    sl.search_fast_counted)
-        with obs.span(name, ops=n):
-            if not obs.counting():
-                return plain(self.index, keys)
-            found, vals, steps = counted(self.index, keys)
-        obs.count_search(steps, per_step=1 if self.cfg.foresight else 2)
-        return found, vals
+        reads = _READS[self.sharded]
+        with obs.span(reads.span, ops=n):
+            return self._launch(reads.lookup, keys)
 
     def get_batch(self, keys: jax.Array) -> Tuple[jax.Array, jax.Array]:
         """Fetch token rows for keys (missing keys fall back to row 0)."""
@@ -189,10 +211,25 @@ class IndexedSampleStore:
         with obs.span("store.get_batch", ops=n):
             if self.mesh is not None:
                 return self._mesh_get_batch(keys)
+            if not self.cfg.use_kernel:
+                # one launch: the search and the row gather run in one
+                # program, so both spans hold it
+                reads = _READS[self.sharded]
+                with obs.span(reads.span, ops=n), \
+                        obs.span("store.gather_rows", ops=n):
+                    return self._launch(reads.read_rows, self.rows, keys)
             found, row_ids = self.lookup(keys)
             with obs.span("store.gather_rows", ops=n):
                 safe = jnp.where(found, row_ids, 0)
                 return self.rows[safe], found
+
+    def _launch(self, read: obs.Read, *args) -> tuple:
+        """Run one of ``_Reads`` on this index; under a profiler trace
+        ``obs`` adds the counted twin's steps."""
+        out, steps = read(self.index, *args)
+        if steps is not None:
+            obs.count_search(steps, per_step=1 if self.cfg.foresight else 2)
+        return out
 
     def _mesh_get_batch(self, keys: jax.Array
                         ) -> Tuple[jax.Array, jax.Array]:

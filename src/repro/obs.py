@@ -16,7 +16,8 @@ steps, times the keys) and the dependent gathers.  With no trace
 nothing is added: the counts cover the traced calls since the last
 reset.  ``snapshot``
 copies them to the host (one sync), ``reset`` zeroes them.  The counters
-are process-wide, like the profiler they sit beside.
+are process-wide, like the profiler they sit beside.  ``Read`` holds a
+jitted read program beside its counted twin and picks one per call.
 
 A read over a mesh of devices (``count_mesh_read``) adds its devices'
 traversals to the same counters, each device's loop running its trips
@@ -46,6 +47,31 @@ def span(name: str, **attrs) -> jax.profiler.TraceAnnotation:
 def counting() -> bool:
     """Whether a profiler trace records, and so the counters add up."""
     return jax.profiler.TraceAnnotation.is_enabled()
+
+
+class Read:
+    """A read program and its counted twin, which also returns each lane's
+    steps, last.  Both are jitted, so each is built once per pytree
+    structure and shape of its arguments.
+
+    A call runs the plain program, or while a profiler trace records the
+    twin, and returns ``(outputs, steps)``, with ``steps`` None when no
+    trace records.  An untraced call that builds the plain program
+    builds the twin beside it, without running it, so that set-up, which
+    warms every shape, leaves a traced window nothing to build."""
+
+    def __init__(self, plain, counted):
+        self.plain, self.counted = jax.jit(plain), jax.jit(counted)
+
+    def __call__(self, *args) -> tuple:
+        if counting():
+            *out, steps = self.counted(*args)
+            return tuple(out), steps
+        built = self.plain._cache_size()
+        out = self.plain(*args)
+        if self.plain._cache_size() > built:
+            self.counted.lower(*args).compile()
+        return out, None
 
 
 @functools.partial(jax.jit, static_argnums=(2,))

@@ -19,7 +19,8 @@ capacity while its neighbours sit empty, and the compiled apply is traced
 ONCE at the ceiling no matter how many shards come and go (batch sizes are
 pow2-padded with no-op reads to bound shape variants).  The old eager-only
 caveat is gone: this is the production serving loop shape — rebalancing
-lives inside the jitted region.
+lives inside the jitted region.  Lookups are jitted too, one read program
+per pow2 size class of the keys.
 
 Composite keys must stay inside int31: ``alloc`` / ``lookup`` / ``release``
 validate ``seq_id < MAX_SEQS`` and ``block_id < 2**BLOCK_BITS`` and raise
@@ -73,6 +74,16 @@ MAX_SEQS = 1 << 18
 
 def page_key(seq_id, block_id):
     return (seq_id << BLOCK_BITS) | block_id
+
+
+def _pow2(n: int) -> int:
+    """The size class of a batch of ``n``: the power of two at or above."""
+    return 1 if n == 0 else 1 << (n - 1).bit_length()
+
+
+# the single-device read: one program per size class of the keys, as
+# lookups are pow2-padded like the apply's batches
+_SEARCH = obs.Read(shd.search_sharded, shd.search_sharded_counted)
 
 
 @dataclasses.dataclass
@@ -168,7 +179,7 @@ class PageTable:
     def _apply(self, ops: jax.Array, keys: jax.Array, vals: jax.Array
                ) -> jax.Array:
         n = ops.shape[0]
-        pad = (1 if n == 0 else 1 << int(n - 1).bit_length()) - n
+        pad = _pow2(n) - n
         with obs.span("page_table.pad", ops=n, padded=pad):
             if pad:  # no-op reads of key 0: no state, RNG, or routing effect
                 ops = jnp.concatenate([ops, jnp.full((pad,), sl.OP_READ,
@@ -185,20 +196,28 @@ class PageTable:
                                                       vals)
         return results[:n]
 
-    def _search(self, keys: jax.Array) -> Tuple[jax.Array, jax.Array]:
-        """Traversal-loop lookup on whichever table variant is live; under
-        a profiler trace the single-device loop adds its counts to
-        ``obs``'s counters."""
+    def _search(self, keys: np.ndarray) -> Tuple[jax.Array, jax.Array]:
+        """Traversal-loop lookup of host ``keys`` on whichever table
+        variant is live; under a profiler trace the single-device loop adds
+        its counts to ``obs``'s counters.
+
+        The single-device read is one program per size class: the keys
+        are padded on the host to the next power of two with key 0 (a
+        read; its answer is sliced off), as ``_apply`` pads its batches."""
         if self.mesh is not None:
-            return mshi.search_mesh(self.index, keys, mesh=self.mesh)
-        n = keys.shape[0]
+            return mshi.search_mesh(self.index, jnp.asarray(keys),
+                                    mesh=self.mesh)
+        n = len(keys)
+        pad = _pow2(n) - n
         with obs.span("read.search_sharded", ops=n):
-            if not obs.counting():
-                return shd.search_sharded(self.index, keys)
-            found, vals, steps = shd.search_sharded_counted(
-                self.index, keys)
-        obs.count_search(steps, per_step=1 if self.cfg.foresight else 2)
-        return found, vals
+            (found, pages), steps = _SEARCH(
+                self.index, jnp.asarray(np.pad(keys, (0, pad))))
+            if steps is not None:
+                obs.count_search(steps[:n] if pad else steps,
+                                 per_step=1 if self.cfg.foresight else 2)
+            if pad:
+                found, pages = found[:n], pages[:n]
+        return found, pages
 
     def _validate_ids(self, seq_ids, block_ids) -> None:
         seq = np.atleast_1d(np.asarray(seq_ids, np.int64))
@@ -234,8 +253,7 @@ class PageTable:
         lost = np.zeros(n, bool)
         if not res.all():
             failed = res == 0
-            still_absent = ~np.asarray(
-                self._search(jnp.asarray(keys[failed]))[0])
+            still_absent = ~np.asarray(self._search(keys[failed])[0])
             if still_absent.any():
                 lost[np.flatnonzero(failed)[still_absent]] = True
                 for p in pages[lost]:
@@ -320,11 +338,11 @@ class PageTable:
         with obs.span("page_table.lookup", ops=n):
             with obs.span("page_table.validate", ops=n):
                 self._validate_ids(seq_ids, block_ids)
-                keys = jnp.asarray(page_key(seq_ids.astype(np.int64),
-                                            block_ids.astype(np.int64))
-                                   .astype(np.int32))
+                keys = page_key(seq_ids.astype(np.int64),
+                                block_ids.astype(np.int64)).astype(np.int32)
             if self.cfg.use_kernel:
-                r = kops.search_kernel(self.index, keys, mesh=self.mesh)
+                r = kops.search_kernel(self.index, jnp.asarray(keys),
+                                       mesh=self.mesh)
                 return r.found, r.vals
             return self._search(keys)
 

@@ -10,7 +10,9 @@ from jax._src import array as jax_array
 from repro import obs
 from repro.core import sharded as shd
 from repro.core import skiplist as sl
+from repro.data import store as store_mod
 from repro.data.store import IndexedSampleStore, StoreConfig
+from repro.serving import kvcache
 from repro.serving.kvcache import PagedCacheConfig, PageTable
 
 
@@ -156,8 +158,9 @@ def test_served_reads_make_no_host_sync(monkeypatch, tmp_path):
 
 
 def test_untraced_reads_run_the_uncounted_traversal(monkeypatch):
-    """With no trace the eager read path lowers the loop without the
-    count's carry: the counted twins are not called."""
+    """With no trace the read path answers from the plain programs, the
+    loop without the count's carry: the counted twins may be built beside
+    them, but are not called."""
     keys, q = _keys(1000)
     store = IndexedSampleStore(StoreConfig(n_samples=1000, seq_len=3),
                                keys=keys)
@@ -166,12 +169,20 @@ def test_untraced_reads_run_the_uncounted_traversal(monkeypatch):
     blocks = np.tile(np.arange(4), 8)
     pt.alloc(seqs, blocks)
 
-    def counted(*a):
-        raise AssertionError("counted traversal outside a trace")
-    monkeypatch.setattr(sl, "search_fast_counted", counted)
-    monkeypatch.setattr(shd, "search_sharded_counted", counted)
+    class Unrun:
+        def __init__(self, program):
+            self.lower = program.lower      # building is allowed
+
+        def __call__(self, *a):
+            raise AssertionError("counted traversal outside a trace")
+    reads = [r for rs in store_mod._READS.values()
+             for r in (rs.lookup, rs.read_rows)] + [kvcache._SEARCH]
+    for read in reads:
+        monkeypatch.setattr(read, "counted", Unrun(read.counted))
     rows, found = store.get_batch(q)
+    found_l, _ = store.lookup(q)
     pfound, pages = pt.lookup(seqs, blocks)
     assert bool(np.asarray(pfound).all())
     f0, _ = sl.search_fast(store.index, q)
     np.testing.assert_array_equal(np.asarray(found), np.asarray(f0))
+    np.testing.assert_array_equal(np.asarray(found_l), np.asarray(f0))

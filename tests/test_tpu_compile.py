@@ -153,6 +153,42 @@ def test_store_apply_copies_no_table_in_its_loops(one_chip):
     assert write.memory_analysis().temp_size_in_bytes <= 3_222_485_504
 
 
+def _table_sized(hlo: str, elems: int) -> list:
+    """Instructions of ``elems`` or more elements anywhere in compiled HLO
+    text but a parameter, a tuple element or a bitcast: a copy, relayout
+    or select of a table that size."""
+    found = []
+    for line in hlo.splitlines():
+        m = _HLO_ARRAY.match(line)
+        if m and math.prod(int(d) for d in m.group(2).split(",") if d) \
+                >= elems and m.group(3) not in (
+                    "parameter", "get-tuple-element", "bitcast"):
+            found.append(f"{m.group(1)} = {m.group(3)}[{m.group(2)}]")
+    return found
+
+
+@pytest.mark.parametrize("batch", [4096, 243])
+def test_store_read_program_reads_the_table_in_place(one_chip, batch):
+    """The store's one-device ``get_batch`` program (the traversal and the
+    row gather in one program, plain and counted) at
+    ``ycsb_store_4m``'s shape: 4M keys, 24 levels, capacity 2^23, rows of
+    250 int32, for a 4,096-key ``ycsb_c`` call and the 243 reads of a
+    ``ycsb_d`` batch.  It reads the fused table where it lies: no
+    temporaries and no table-sized instruction but the parameter."""
+    from repro.data import store
+    n, levels, cap, width = 4_000_000, 24, 2**23, 250
+    state = _on(one_chip, jax.eval_shape(
+        functools.partial(sl.build, capacity=cap, levels=levels),
+        _spec(one_chip, (n,)), _spec(one_chip, (n,))))
+    read = store._READS[False].read_rows
+    for program in (read.plain, read.counted):
+        compiled = program.lower(state, _spec(one_chip, (n, width)),
+                                 _spec(one_chip, (batch,))).compile()
+        _fits(compiled)
+        assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+        assert _table_sized(compiled.as_text(), levels * cap) == []
+
+
 def test_page_table_apply_copies_no_table_in_its_loops(one_chip):
     """``jax.vmap(apply_ops)`` over the page table's stacked shards (8
     shards x 16 levels x 32,768 slots, windows of 32 ops), as
